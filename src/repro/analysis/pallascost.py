@@ -39,7 +39,7 @@ block each program's window DMAs into VMEM).  This module turns that into
 Index maps are interpreted, not executed: a tiny numpy evaluator covers
 the quasi-affine vocabulary real maps use (±, ×-by-constant, truncating
 ``div``/``rem`` by constants — ``lax``'s C-style semantics, not numpy's
-flooring ``//`` — comparisons, ``select_n``, nested ``pjit``).  Anything
+flooring ``//`` — comparisons, ``select_n``, nested ``jit``).  Anything
 outside that vocabulary, a data-dependent grid, or scalar-prefetch
 operands raises :class:`PallasUnanalyzable` with a precise reason
 (``non-affine-index-map`` / ``dynamic-grid`` / ``scalar-prefetch``) that
@@ -55,6 +55,7 @@ from typing import Any, Callable, Dict, List, Optional, Tuple
 import numpy as np
 
 from repro.core.counting import (
+    CALL_PRIMITIVES,
     FeatureCounts,
     _count_jaxpr_into,
     _dt,
@@ -245,8 +246,7 @@ def _interp_eqn(eqn, env: Dict[Any, _Val]) -> None:
         if eqn.outvars[0].aval.shape != ():
             raise _NonAffine(f"non-scalar {prim!r} in an index map")
         return out(_Val(ins[0].arr, ins[0].dep))
-    if prim in ("pjit", "closed_call", "core_call", "remat", "checkpoint",
-                "custom_jvp_call", "custom_vjp_call"):
+    if prim in CALL_PRIMITIVES:
         sub = eqn.params.get("jaxpr") or eqn.params.get("call_jaxpr")
         jx = sub.jaxpr if hasattr(sub, "jaxpr") else sub
         consts = list(getattr(sub, "consts", ()))
@@ -351,10 +351,13 @@ def _is_any_space(aval) -> bool:
 
 
 def _block_elems(block_shape) -> int:
+    # entries are ``Blocked``/``Element`` dims (``block_size``) or
+    # ``Squeezed`` (a unit dim with no size)
     n = 1
     for b in block_shape:
-        if isinstance(b, (int, np.integer)):
-            n *= int(b)
+        size = getattr(b, "block_size", b)
+        if isinstance(size, (int, np.integer)):
+            n *= int(size)
     return n
 
 
@@ -480,9 +483,7 @@ def analyze_pallas_call(eqn) -> PallasCost:
                 finally:
                     mask_stack.pop()
             return True
-        if prim in ("pjit", "closed_call", "core_call", "remat",
-                    "checkpoint", "custom_jvp_call", "custom_vjp_call",
-                    "custom_vjp_call_jaxpr"):
+        if prim in CALL_PRIMITIVES:
             sub = sub_eqn.params.get("jaxpr") \
                 or sub_eqn.params.get("call_jaxpr")
             if sub is not None:
@@ -527,7 +528,7 @@ def analyze_pallas_call(eqn) -> PallasCost:
                 f"operand {pos} ({role}) index map is not quasi-affine "
                 f"in the grid indices: {e.detail}") from None
         fetches = _fetches(outs) if exact else num_programs
-        dt = str(bm.array_shape_dtype.dtype)
+        dt = str(bm.array_aval.dtype)
         t = OperandTraffic(role=role, index=idx, dtype=dt,
                            block_elems=_block_elems(bm.block_shape),
                            fetches=fetches, exact=exact)

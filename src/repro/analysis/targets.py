@@ -77,7 +77,7 @@ def kernel_targets() -> List[KernelTarget]:
             (_f32(3, 64, 64), _f32(64, 1024))),
         KernelTarget(
             "kernels.ops.stream_strided",
-            functools.partial(ops.stream_strided, block=256, stride=2),
+            functools.partial(ops.stream_strided, block=1024, stride=2),
             ([_f32(8192), _f32(8192)],)),
         KernelTarget(
             "kernels.ops.madd_throughput",
@@ -86,5 +86,5 @@ def kernel_targets() -> List[KernelTarget]:
         KernelTarget(
             "kernels.ops.slstm_cell",
             ops.slstm_cell,
-            (_f32(2, 24, 4, 4, 16), _f32(4, 16, 4, 16), _f32(4, 4, 16))),
+            (_f32(2, 32, 4, 4, 16), _f32(4, 16, 4, 16), _f32(4, 4, 16))),
     ]

@@ -18,7 +18,9 @@ calibrations — per machine, per model variant — pay tracing once.
 from __future__ import annotations
 
 from dataclasses import dataclass
-from typing import Callable, Dict, Mapping, Optional, Sequence, Tuple
+from typing import (
+    Callable, Collection, Dict, Mapping, Optional, Sequence, Tuple, Union,
+)
 
 import jax
 import jax.numpy as jnp
@@ -72,7 +74,7 @@ def _lm_core(
     lam_up: float,
     lam_down: float,
     tol: float,
-    nonneg: bool,
+    nonneg: Union[bool, Tuple[bool, ...]],
     inner_tries: int = 20,
 ) -> Tuple[jax.Array, jax.Array, jax.Array, jax.Array]:
     """Classic LM with multiplicative damping adaptation, as one
@@ -80,7 +82,8 @@ def _lm_core(
 
     ``nonneg=True`` clamps parameters at 0 after each accepted step —
     the paper's cost-explanatory interpretability requirement (§4: negative
-    per-operation costs are inconsistent with the notion of 'cost').
+    per-operation costs are inconsistent with the notion of 'cost').  A
+    per-parameter tuple of flags clamps only the flagged positions.
 
     Returns ``(p, cost, iterations, converged)`` as traced arrays.
     """
@@ -95,8 +98,9 @@ def _lm_core(
         A = JTJ + lam * jnp.diag(diag)
         dp = jnp.linalg.solve(A, -JTr)
         p_new = p + dp
-        if nonneg:
-            p_new = jnp.maximum(p_new, 0.0)
+        if nonneg is not False:
+            p_new = jnp.where(jnp.asarray(nonneg), jnp.maximum(p_new, 0.0),
+                              p_new)
         r_new = resid_fn(p_new)
         cost_new = jnp.sum(r_new * r_new)
         ok = (jnp.isfinite(dp).all() & jnp.isfinite(cost_new)
@@ -131,8 +135,10 @@ def _lm_core(
     def outer_body(s):
         p, r, cost, lam, it, converged, done = s
         J = jac(p)
-        JTJ = J.T @ J
-        JTr = J.T @ r
+        # full-f32 normal equations: a TPU runs default-precision f32
+        # matmuls as bf16 passes, which would shift the fitted params
+        JTJ = jnp.matmul(J.T, J, precision=jax.lax.Precision.HIGHEST)
+        JTr = jnp.matmul(J.T, r, precision=jax.lax.Precision.HIGHEST)
         _, lam_n, accepted, p_c, r_c, cost_c = damping_search(
             p, r, cost, JTJ, JTr, lam)
         rel = (cost - cost_c) / jnp.maximum(cost, 1e-30)
@@ -189,7 +195,8 @@ _SHARED_SOLVER_CACHE: Dict[tuple, Callable] = {}
 _SHARED_SOLVER_CACHE_MAX = 64
 
 
-def _batch_solver(model: Model, *, nonneg: bool, max_iters: int, lam0: float,
+def _batch_solver(model: Model, *, nonneg: Union[bool, Tuple[bool, ...]],
+                  max_iters: int, lam0: float,
                   lam_up: float, lam_down: float, tol: float) -> Callable:
     """Compiled ``(F, target, starts) -> best (p, cost, it, conv)`` solver;
     cached on the model AND in the process-wide signature-keyed cache so
@@ -253,7 +260,7 @@ def fit_model(
     *,
     scale_by_output: bool = True,
     p0: Optional[Mapping[str, float]] = None,
-    nonneg: bool = False,
+    nonneg: Union[bool, Collection[str]] = False,
     seeds: int = 3,
     max_iters: int = 200,
     lam0: float = 1e-3,
@@ -266,12 +273,15 @@ def fit_model(
     ``feature_table`` may be a :class:`repro.core.model.FeatureTable` or the
     original one-dict-per-row representation.  All restarts solve in a
     single compiled vmap-of-while-loop call; the best fit (lowest residual)
-    is returned.
+    is returned.  ``nonneg`` is ``True`` (every parameter is a cost, kept
+    ≥ 0), ``False``, or the names of the parameters to keep ≥ 0.
     """
     table = as_feature_table(feature_table)
     F_np, target_np = model.design_matrix(
         table, scale_by_output=scale_by_output)
     names = model.param_names
+    if not isinstance(nonneg, bool):
+        nonneg = tuple(n in nonneg for n in names)
     dt = _param_dtype()
 
     p_init = jnp.full((len(names),), 1e-9, dt)
@@ -300,7 +310,7 @@ def fit_models(
     feature_table: FeatureTableLike,
     *,
     scale_by_output: bool = True,
-    nonneg: Optional[Mapping[str, bool]] = None,
+    nonneg: Optional[Mapping[str, Union[bool, Collection[str]]]] = None,
     seeds: int = 3,
     warm_start: bool = True,
     **solver_opts,
@@ -322,8 +332,8 @@ def fit_models(
     The table is densified once; each model's compiled solver comes from
     the signature-keyed solver cache, so a study re-run (or the same zoo
     fitted on the next machine) pays zero re-tracing.  ``nonneg`` maps
-    model name → nonnegativity constraint (default True, the paper's
-    cost-explanatory setting).
+    model name → nonnegativity constraint, as :func:`fit_model` takes it
+    (default True, the paper's cost-explanatory setting).
     """
     table = as_feature_table(feature_table)
     nonneg = dict(nonneg or {})

@@ -74,8 +74,8 @@ def reference_levenberg_marquardt(
     converged = False
     for it in range(1, max_iters + 1):
         J = jac(p)
-        JTJ = J.T @ J
-        JTr = J.T @ r
+        JTJ = jnp.matmul(J.T, J, precision=jax.lax.Precision.HIGHEST)
+        JTr = jnp.matmul(J.T, r, precision=jax.lax.Precision.HIGHEST)
         stepped = False
         for _ in range(20):  # inner damping search
             A = JTJ + lam * jnp.diag(jnp.maximum(jnp.diag(JTJ), 1e-20))

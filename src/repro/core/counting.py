@@ -7,7 +7,8 @@ by the (statically known) trip count, ``cond`` branches are averaged
 (matching the paper's divergent-control-flow cost accounting — except
 inside Pallas kernel bodies, where the static cost analyzer resolves
 ``program_id``-derived predicates and charges each grid program its
-actual branch), and ``pjit``/``remat`` calls are inlined.
+actual branch), and ``jit``/``remat`` calls (:data:`CALL_PRIMITIVES`) are
+inlined.
 
 Counted feature classes (the TPU translation of the paper's features):
   * arithmetic  — by (op-kind, dtype); ``dot_general`` is counted as *madd*
@@ -89,7 +90,8 @@ _ARITH = {
 
 _MEM_GATHER = {"gather", "take", "dynamic_slice"}
 _MEM_SCATTER = {"scatter", "scatter-add", "scatter_add", "dynamic_update_slice"}
-_MEM_STRIDED = {"transpose", "rev"}
+# element permutations; ``roll`` is Pallas TPU's lane/sublane rotation
+_MEM_STRIDED = {"transpose", "rev", "roll"}
 # concatenate gets its own access class: on most hosts it materializes a
 # copy (jnp.roll lowers to it), with a distinct cost from streaming adds
 _MEM_CONCAT = {"concatenate"}
@@ -104,14 +106,11 @@ _MEM_REF = {"get", "swap", "addupdate"}
 
 _COLLECTIVES = {"psum", "all_gather", "reduce_scatter", "all_to_all",
                 "ppermute", "pmax", "pmin", "psum_invariant",
-                "all_gather_invariant", "psum2"}
+                "all_gather_invariant"}
 
 
 def _coll_name(prim: str) -> str:
-    if prim.endswith("_invariant"):
-        prim = prim[:-10]
-    # jax 0.4.x shard_map lowers psum to the distinct psum2 primitive
-    return prim[:-1] if prim.endswith("2") else prim
+    return prim[:-len("_invariant")] if prim.endswith("_invariant") else prim
 
 _REDUCE = {"reduce_sum": "add", "reduce_max": "cmp", "reduce_min": "cmp",
            "reduce_prod": "mul", "argmax": "cmp", "argmin": "cmp",
@@ -122,14 +121,18 @@ _REDUCE = {"reduce_sum": "add", "reduce_max": "cmp", "reduce_min": "cmp",
 # Count vocabulary (exported for the static scope auditor, repro.analysis)
 # ---------------------------------------------------------------------------
 
+#: call primitives: a sub-jaxpr (``jaxpr`` or ``call_jaxpr`` param) run
+#: once, so its cost is its body's cost.  The one table every walker
+#: (counting, work removal, the pallas analyzer) dispatches on — ``jax.jit``
+#: binds ``jit`` and ``jax.checkpoint`` binds ``remat2``.
+CALL_PRIMITIVES = ("jit", "closed_call", "core_call", "remat2",
+                   "custom_jvp_call", "custom_vjp_call")
+
 # control-flow primitives the walker RECURSES into (their cost is their
 # body's cost, possibly times a trip count) — must list exactly the prims
 # _count_eqn handles structurally, or the auditor would misclassify them
-CONTROL_PRIMITIVES = frozenset({
-    "scan", "while", "cond", "pjit", "closed_call", "core_call", "remat",
-    "checkpoint", "custom_jvp_call", "custom_vjp_call",
-    "custom_vjp_call_jaxpr", "shard_map",
-})
+CONTROL_PRIMITIVES = frozenset(
+    {"scan", "while", "cond", "shard_map", *CALL_PRIMITIVES})
 
 # primitives the counter DELIBERATELY treats as free.  These never earn a
 # feature: predicates/bit ops ride along with the selects and arithmetic
@@ -355,9 +358,7 @@ def _count_eqn(eqn, counts: FeatureCounts, mult: float,
             _count_jaxpr_into(br.jaxpr, counts, mult / len(branches),
                               override=override)
         return
-    if prim in ("pjit", "closed_call", "core_call", "remat", "checkpoint",
-                "custom_jvp_call", "custom_vjp_call", "custom_vjp_call_jaxpr",
-                "shard_map"):
+    if prim in CALL_PRIMITIVES or prim == "shard_map":
         sub = eqn.params.get("jaxpr") or eqn.params.get("call_jaxpr")
         if sub is not None:
             jx = sub.jaxpr if hasattr(sub, "jaxpr") else sub

@@ -7,7 +7,7 @@ storing it so the compiler cannot dead-code-eliminate the access.
 
 The JAX realization interprets a ClosedJaxpr with a rewriting evaluator:
 
-  * control flow (``scan``/``cond``/``pjit``/``remat``) is preserved by
+  * control flow (``scan``/``cond``/``jit``/``remat``) is preserved by
     recursing into sub-jaxprs — loop environments (and therefore per-
     iteration access counts / AFR) survive,
   * compute equations (``dot_general``, transcendentals, mul/div, …) are
@@ -30,6 +30,8 @@ from typing import Any, Callable, Dict, Sequence, Set
 
 import jax
 import jax.numpy as jnp
+
+from repro.core.counting import CALL_PRIMITIVES
 
 # primitives whose *computation* is stripped (memory reads of their kept
 # operands are preserved through the reduce_sum proxy)
@@ -141,8 +143,8 @@ def _eval_jaxpr_stripped(jaxpr, read, write, dead=None) -> jax.Array:
                 write(ov, proxy)
             continue
 
-        if all_dead and prim not in ("scan", "pjit", "closed_call", "remat",
-                                     "checkpoint", "cond", "while"):
+        if all_dead and prim not in ("scan", "cond", "while",
+                                     *CALL_PRIMITIVES):
             # access chain of a removed array: emit zeros, mark dead —
             # the load disappears from the stripped kernel's features too
             for ov in eqn.outvars:
@@ -195,7 +197,7 @@ def _eval_jaxpr_stripped(jaxpr, read, write, dead=None) -> jax.Array:
                 write(ov, o)
             continue
 
-        if prim in ("pjit", "closed_call", "remat", "checkpoint"):
+        if prim in CALL_PRIMITIVES:
             sub = eqn.params.get("jaxpr") or eqn.params.get("call_jaxpr")
             ij = sub.jaxpr if hasattr(sub, "jaxpr") else sub
             sub_env: Dict[Any, Any] = {}

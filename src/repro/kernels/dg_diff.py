@@ -15,13 +15,14 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams
+from repro.kernels import mxu_precision
 
 
 def _dg_kernel(d_ref, ut_ref, o_ref):
     d = d_ref[0]            # [N, N]
     ut = ut_ref[...]        # [N, be]  (transposed element data)
-    o_ref[0] = jnp.dot(d, ut, preferred_element_type=jnp.float32).astype(
+    o_ref[0] = jnp.dot(d, ut, precision=mxu_precision(d.dtype),
+                       preferred_element_type=jnp.float32).astype(
         o_ref.dtype)
 
 
@@ -47,7 +48,7 @@ def dg_diff(
         ],
         out_specs=pl.BlockSpec((1, N, be), lambda m, e: (m, 0, e)),
         out_shape=jax.ShapeDtypeStruct((M, N, K), ut.dtype),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")),
         interpret=interpret,
     )(diff_mat, ut)

@@ -24,7 +24,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams
+from repro.kernels import mxu_precision
 
 NEG_INF = -1e30
 
@@ -41,12 +41,12 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    q = q_ref[0, :, 0, :]  # [bq, D]
-    k = k_ref[0, :, 0, :]  # [bk, D]
-    v = v_ref[0, :, 0, :]  # [bk, Dv]
+    q = q_ref[...]  # [bq, D]
+    k = k_ref[...]  # [bk, D]
+    v = v_ref[...]  # [bk, Dv]
 
     s = jax.lax.dot_general(
-        q, k, (((1,), (1,)), ((), ())),
+        q, k, (((1,), (1,)), ((), ())), precision=mxu_precision(q.dtype),
         preferred_element_type=jnp.float32) * scale      # [bq, bk]
     if softcap is not None:
         s = softcap * jnp.tanh(s / softcap)
@@ -71,13 +71,14 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
     l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=1)
     acc_ref[...] = acc_ref[...] * corr[:, None] + jax.lax.dot_general(
         p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+        precision=mxu_precision(v.dtype),
         preferred_element_type=jnp.float32)
     m_ref[...] = m_new
 
     @pl.when(ik == n_k - 1)
     def _done():
         l = jnp.maximum(l_ref[...], 1e-30)
-        o_ref[0, :, 0, :] = (acc_ref[...] / l[:, None]).astype(o_ref.dtype)
+        o_ref[...] = (acc_ref[...] / l[:, None]).astype(o_ref.dtype)
 
 
 def flash_attention(
@@ -106,26 +107,32 @@ def flash_attention(
         _flash_kernel, scale=scale, causal=causal, window=window,
         softcap=softcap, block_q=bq, block_k=bk, n_k=n_k)
 
-    return pl.pallas_call(
+    # heads before sequence: Mosaic tiles the last two block dims, so the
+    # (bq, D) tile must be the trailing pair — a (bq, 1, D) slice of the
+    # [B, S, H, D] layout is refused
+    qt, kt, vt = (x.transpose(0, 2, 1, 3) for x in (q, k, v))
+    out = pl.pallas_call(
         kernel,
         grid=(B, Hq, Sq // bq, n_k),
         in_specs=[
-            pl.BlockSpec((1, bq, 1, D), lambda b, h, iq, ik: (b, iq, h, 0)),
-            pl.BlockSpec((1, bk, 1, D),
-                         lambda b, h, iq, ik, G=G: (b, ik, h // G, 0)),
-            pl.BlockSpec((1, bk, 1, Dv),
-                         lambda b, h, iq, ik, G=G: (b, ik, h // G, 0)),
+            pl.BlockSpec((None, None, bq, D),
+                         lambda b, h, iq, ik: (b, h, iq, 0)),
+            pl.BlockSpec((None, None, bk, D),
+                         lambda b, h, iq, ik, G=G: (b, h // G, ik, 0)),
+            pl.BlockSpec((None, None, bk, Dv),
+                         lambda b, h, iq, ik, G=G: (b, h // G, ik, 0)),
         ],
-        out_specs=pl.BlockSpec((1, bq, 1, Dv),
-                               lambda b, h, iq, ik: (b, iq, h, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, Sq, Hq, Dv), q.dtype),
+        out_specs=pl.BlockSpec((None, None, bq, Dv),
+                               lambda b, h, iq, ik: (b, h, iq, 0)),
+        out_shape=jax.ShapeDtypeStruct((B, Hq, Sq, Dv), q.dtype),
         scratch_shapes=[
             pltpu.VMEM((bq,), jnp.float32),
             pltpu.VMEM((bq,), jnp.float32),
             pltpu.VMEM((bq, Dv), jnp.float32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
-    )(q, k, v)
+    )(qt, kt, vt)
+    return out.transpose(0, 2, 1, 3)

@@ -16,7 +16,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams
+from repro.kernels import mxu_precision
 
 
 def _matmul_kernel(a_ref, b_ref, o_ref, acc_ref, *, n_k: int):
@@ -27,7 +27,8 @@ def _matmul_kernel(a_ref, b_ref, o_ref, acc_ref, *, n_k: int):
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     acc_ref[...] += jnp.dot(
-        a_ref[...], b_ref[...], preferred_element_type=jnp.float32)
+        a_ref[...], b_ref[...], precision=mxu_precision(a_ref.dtype),
+        preferred_element_type=jnp.float32)
 
     @pl.when(k == n_k - 1)
     def _done():
@@ -61,7 +62,7 @@ def matmul_tiled(
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((M, N), a.dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(a, b)

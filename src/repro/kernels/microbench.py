@@ -3,7 +3,9 @@
 ``stream_strided`` — the paper's parameterized global-memory access-pattern
 microbenchmark: the *block-stride* argument is the TPU analogue of the
 paper's group-ID stride (which block of HBM each grid step touches), and
-dtype/width map directly.
+dtype/width map directly.  XLA lays a 1-D array out on the TPU in tiles of
+1024 elements, and Mosaic refuses a 1-D block whose tiling differs, so
+1-D blocks here are multiples of :data:`BLOCK_ALIGN`.
 
 ``madd_throughput`` — the paper's peak-FLOP kernel (SHOC MaxFlops pattern):
 a VMEM-resident block is updated by an ``iters``-deep fused multiply-add
@@ -17,7 +19,9 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
+
+#: element granularity of a 1-D block that matches XLA's TPU layout
+BLOCK_ALIGN = 1024
 
 
 def _stream_kernel(*refs):
@@ -31,10 +35,11 @@ def _stream_kernel(*refs):
 def stream_strided(
     arrays,                # list of [S] inputs, S = n_blocks·stride·block
     *,
-    block: int = 512,
+    block: int = BLOCK_ALIGN,
     stride: int = 1,       # block-stride: which HBM blocks each step reads
     interpret: bool = False,
 ) -> jax.Array:
+    assert block % BLOCK_ALIGN == 0, (block, BLOCK_ALIGN)
     (S,) = arrays[0].shape
     n_out = S // (block * stride)
     assert n_out * block * stride == S
@@ -76,7 +81,7 @@ def madd_throughput(
 ) -> jax.Array:
     (S,) = x.shape
     blk = min(block, S)
-    assert S % blk == 0
+    assert S % blk == 0 and blk % BLOCK_ALIGN == 0, (S, blk, BLOCK_ALIGN)
     return pl.pallas_call(
         functools.partial(_madd_kernel, iters=iters, a=a, b=b),
         grid=(S // blk,),

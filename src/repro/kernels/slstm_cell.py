@@ -2,16 +2,22 @@
 
 The xlstm-125m prefill roofline is dominated by the per-timestep recurrent
 matmul re-reading ``r_gates`` (2.4 MB) from HBM 32768 times per layer.
-This kernel runs the whole time loop *inside* one grid step with the
-recurrent weights pinned in VMEM: HBM traffic drops to one streaming read
-of the precomputed input-gate contributions ``g_in`` and one write of the
-hidden trajectory — the roofline lower bound for a sequential recurrence.
+This kernel runs the time loop *inside* the kernel with the recurrent
+weights pinned in VMEM: HBM traffic drops to one streaming read of the
+precomputed input-gate contributions ``g_in`` and one write of the hidden
+trajectory — the roofline lower bound for a sequential recurrence.
 
 Stabilized exponential gating (running per-cell max ``m``), identical math
 to ``repro.models.xlstm._slstm_cell``.
 
-Grid: one program per batch row (the recurrence serializes time anyway);
-weights are broadcast to every program by the BlockSpec index map.
+Layout: the wrapper makes the operands time-major with the batch on
+sublanes (``g_in`` → ``[S, 4, H, B, dh]``), so each (gate, head) slice the
+loop reads is a ``[B, dh]`` tile and each recurrent product is one
+``[B, dh] @ [dh, dh]`` MXU matmul — no in-kernel reshape or transpose.
+Grid: one program per ``SEQ_TILE`` time steps, sequential ("arbitrary"),
+with the (c, n, m, h) state carried across programs in VMEM scratch; the
+sequence tile keeps the streamed blocks inside the scoped VMEM budget at
+published widths (xlstm-125m: 4 heads × 192, seq 2048).
 """
 from __future__ import annotations
 
@@ -22,43 +28,49 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams
+from repro.kernels import mxu_precision
+
+
+_F32 = mxu_precision(jnp.float32)
+SEQ_TILE = 16       # time steps per grid program
 
 
 def _slstm_kernel(g_in_ref, r_ref, b_ref, y_ref, c_ref, n_ref, m_ref, h_ref,
-                  *, steps: int, H: int, dh: int):
-    c_ref[...] = jnp.zeros_like(c_ref)
-    n_ref[...] = jnp.zeros_like(n_ref)
-    m_ref[...] = jnp.zeros_like(m_ref)
-    h_ref[...] = jnp.zeros_like(h_ref)
-    r = r_ref[...].astype(jnp.float32)          # [H, dh, 4*dh] — VMEM-resident
-    b = b_ref[...].astype(jnp.float32)          # [4, H, dh]
+                  *, H: int):
+    @pl.when(pl.program_id(0) == 0)
+    def _init():
+        c_ref[...] = jnp.zeros_like(c_ref)
+        n_ref[...] = jnp.zeros_like(n_ref)
+        m_ref[...] = jnp.zeros_like(m_ref)
+        h_ref[...] = jnp.zeros_like(h_ref)
 
     def step(t, _):
-        g_in = g_in_ref[0, t].astype(jnp.float32)   # [4, H, dh]
-        h = h_ref[...]
-        # block-diagonal recurrence: per head, h · r → 4 gate contributions
-        rec = jax.lax.dot_general(
-            h[:, None, :], r, (((2,), (1,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32)     # [H, 1, 4*dh]
-        rec = rec.reshape(H, 4, dh).transpose(1, 0, 2)  # [4, H, dh]
-        g = g_in + rec + b
-        li, lf, z_raw, o_raw = g[0], g[1], g[2], g[3]
-        lf = jax.nn.log_sigmoid(lf)
-        m_new = jnp.maximum(lf + m_ref[...], li)
-        ip = jnp.exp(li - m_new)
-        fp = jnp.exp(lf + m_ref[...] - m_new)
-        c_new = fp * c_ref[...] + ip * jnp.tanh(z_raw)
-        n_new = fp * n_ref[...] + ip
-        h_new = jax.nn.sigmoid(o_raw) * c_new / jnp.maximum(n_new, 1e-6)
-        c_ref[...] = c_new
-        n_ref[...] = n_new
-        m_ref[...] = m_new
-        h_ref[...] = h_new
-        y_ref[0, t] = h_new.astype(y_ref.dtype)
+        for hd in range(H):
+            h = h_ref[hd]                                   # [B, dh]
+            # per head and gate: input contribution + h · r + bias
+            li, lf, z_raw, o_raw = (
+                g_in_ref[t, g, hd].astype(jnp.float32)
+                + jnp.dot(h, r_ref[hd, g].astype(jnp.float32),
+                          precision=_F32,
+                          preferred_element_type=jnp.float32)
+                + b_ref[g, hd].astype(jnp.float32)
+                for g in range(4))
+            lf = jax.nn.log_sigmoid(lf)
+            m_prev = m_ref[hd]
+            m_new = jnp.maximum(lf + m_prev, li)
+            ip = jnp.exp(li - m_new)
+            fp = jnp.exp(lf + m_prev - m_new)
+            c_new = fp * c_ref[hd] + ip * jnp.tanh(z_raw)
+            n_new = fp * n_ref[hd] + ip
+            h_new = jax.nn.sigmoid(o_raw) * c_new / jnp.maximum(n_new, 1e-6)
+            c_ref[hd] = c_new
+            n_ref[hd] = n_new
+            m_ref[hd] = m_new
+            h_ref[hd] = h_new
+            y_ref[t, hd] = h_new.astype(y_ref.dtype)
         return ()
 
-    jax.lax.fori_loop(0, steps, step, ())
+    jax.lax.fori_loop(0, SEQ_TILE, step, ())
 
 
 def slstm_cell(
@@ -68,29 +80,31 @@ def slstm_cell(
     *,
     interpret: bool = False,
 ) -> jax.Array:
-    """Returns the hidden trajectory h: [B, S, H, dh]."""
+    """Returns the hidden trajectory h: [B, S, H, dh].  ``S`` must be a
+    multiple of ``SEQ_TILE``."""
     B, S, four, H, dh = g_in.shape
     assert four == 4
-    r2 = r_gates.reshape(H, dh, 4 * dh)
+    if S % SEQ_TILE:
+        raise ValueError(f"slstm_cell: sequence length {S} is not a multiple "
+                         f"of the {SEQ_TILE}-step tile")
+    g_t = g_in.transpose(1, 2, 3, 0, 4)                 # [S, 4, H, B, dh]
+    r_t = r_gates.transpose(0, 2, 1, 3)                 # [H, 4, dh, dh]
+    b_t = b_gates[:, :, None, :]                        # [4, H, 1, dh]
 
-    kernel = functools.partial(_slstm_kernel, steps=S, H=H, dh=dh)
-    return pl.pallas_call(
+    kernel = functools.partial(_slstm_kernel, H=H)
+    y = pl.pallas_call(
         kernel,
-        grid=(B,),
+        grid=(S // SEQ_TILE,),
         in_specs=[
-            pl.BlockSpec((1, S, 4, H, dh), lambda b: (b, 0, 0, 0, 0)),
-            pl.BlockSpec((H, dh, 4 * dh), lambda b: (0, 0, 0)),
-            pl.BlockSpec((4, H, dh), lambda b: (0, 0, 0)),
+            pl.BlockSpec((SEQ_TILE, 4, H, B, dh), lambda s: (s, 0, 0, 0, 0)),
+            pl.BlockSpec((H, 4, dh, dh), lambda s: (0, 0, 0, 0)),
+            pl.BlockSpec((4, H, 1, dh), lambda s: (0, 0, 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, S, H, dh), lambda b: (b, 0, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, S, H, dh), g_in.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((H, dh), jnp.float32),  # c
-            pltpu.VMEM((H, dh), jnp.float32),  # n
-            pltpu.VMEM((H, dh), jnp.float32),  # m
-            pltpu.VMEM((H, dh), jnp.float32),  # h
-        ],
-        compiler_params=CompilerParams(
-            dimension_semantics=("parallel",)),
+        out_specs=pl.BlockSpec((SEQ_TILE, H, B, dh), lambda s: (s, 0, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((S, H, B, dh), g_in.dtype),
+        scratch_shapes=[pltpu.VMEM((H, B, dh), jnp.float32)] * 4,  # c n m h
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
         interpret=interpret,
-    )(g_in, r2, b_gates)
+    )(g_t, r_t, b_t)
+    return y.transpose(2, 0, 1, 3)

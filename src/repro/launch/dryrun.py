@@ -1,6 +1,3 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-
 """Multi-pod dry-run: lower + compile every (arch × shape × mesh) cell.
 
 For each cell this driver:
@@ -14,12 +11,16 @@ For each cell this driver:
      roofline pass re-derives FLOPs/bytes/collectives itself), and
   6. writes a JSON record consumed by EXPERIMENTS.md §Dry-run/§Roofline.
 
+It is a CPU rehearsal: ``main()`` pins the CPU platform and asks XLA for
+512 virtual host devices, so the dry-run never takes an attached chip.
+
 Usage:
   PYTHONPATH=src python -m repro.launch.dryrun --arch yi-6b --shape train_4k \
       --mesh single --out runs/dryrun
 """
 import argparse
 import json
+import os
 import time
 import traceback
 from pathlib import Path
@@ -31,7 +32,6 @@ from jax.sharding import PartitionSpec as P
 
 from repro.configs import SHAPES_BY_NAME, get_config, shapes_for
 from repro.launch import specs as S
-from repro.compat import jit_cost_analysis
 from repro.launch.mesh import make_production_mesh
 from repro.launch.presets import make_run_config
 from repro.launch.steps import make_decode_step, make_prefill_step, make_train_step
@@ -132,7 +132,7 @@ def run_cell(arch: str, shape_name: str, mesh_kind: str, out_dir: Path,
             mem["argument_bytes"] + mem["output_bytes"] + mem["temp_bytes"]
             - mem["alias_bytes"])
         rec["memory"] = mem
-        ca = jit_cost_analysis(compiled)
+        ca = compiled.cost_analysis() or {}
         print({k: ca.get(k) for k in ("flops", "bytes accessed")})
         rec["xla_cost_analysis"] = {
             "flops": float(ca.get("flops", 0.0)),
@@ -167,6 +167,11 @@ def run_cell(arch: str, shape_name: str, mesh_kind: str, out_dir: Path,
 
 
 def main():
+    # before the first backend query: virtual devices, and never the chip
+    os.environ["XLA_FLAGS"] = " ".join(filter(None, [
+        os.environ.get("XLA_FLAGS"),
+        "--xla_force_host_platform_device_count=512"]))
+    jax.config.update("jax_platforms", "cpu")
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--shape", required=True)

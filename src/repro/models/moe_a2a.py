@@ -31,7 +31,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map
 from repro.configs.base import ModelConfig
 from repro.models import layers
 from repro.sharding import current_mesh
@@ -67,7 +66,7 @@ def apply_moe_a2a(p: Dict, cfg: ModelConfig, x: jax.Array
     router_spec = P()
 
     @functools.partial(
-        shard_map,
+        jax.shard_map,
         mesh=mesh,
         in_specs=(x_spec, router_spec, w_spec, w_spec, w_spec),
         out_specs=(x_spec, P(), P()),

@@ -24,7 +24,9 @@ class OptState(NamedTuple):
 
 def init_opt_state(params: Any, ocfg: OptimizerConfig) -> OptState:
     mdt = jnp.dtype(ocfg.moment_dtype)
-    zeros = lambda p: jnp.zeros(p.shape, mdt)
+    # zeros_like keeps each parameter's sharding: the moments are born
+    # split across the mesh as the parameters are, not gathered on one chip
+    zeros = lambda p: jnp.zeros_like(p, dtype=mdt)
     return OptState(
         mu=jax.tree.map(zeros, params),
         nu=jax.tree.map(zeros, params),

@@ -22,6 +22,8 @@ from typing import Any, Callable, Dict, Iterator, List, Optional
 
 import jax
 import numpy as np
+from jax.sharding import NamedSharding
+from jax.sharding import PartitionSpec as P
 
 from repro.checkpoint import CheckpointManager
 from repro.configs.base import RunConfig
@@ -66,16 +68,21 @@ class Trainer:
             if self.mesh is not None:
                 self._param_sh = tree_shardings(
                     lm.param_axes(self.cfg), self._abs_params, mesh=self.mesh)
+                # the step counter lives replicated, as the step returns it:
+                # an uncommitted counter would make step 2 recompile
                 self._opt_sh = adamw.opt_state_axes(self._param_sh)._replace(
-                    count=None)
+                    count=NamedSharding(self.mesh, P()))
             else:
                 self._param_sh = self._opt_sh = None
             step_fn = make_train_step(self.run)
             donate = (0, 1)
             if self.mesh is not None:
+                # the step hands back its state in the layout it takes it
+                # in; left to the compiler, an output sharding can differ
+                # and the next step's jit refuses its own state
+                state_sh = (self._param_sh, self._opt_sh, None)
                 self._train_step = jax.jit(
-                    step_fn,
-                    in_shardings=(self._param_sh, self._opt_sh, None),
+                    step_fn, in_shardings=state_sh, out_shardings=state_sh,
                     donate_argnums=donate)
             else:
                 self._train_step = jax.jit(step_fn, donate_argnums=donate)
@@ -87,6 +94,9 @@ class Trainer:
             if self._param_sh is not None:
                 params = jax.tree.map(jax.device_put, params, self._param_sh)
             opt = adamw.init_opt_state(params, self.run.optimizer)
+            if self._opt_sh is not None:
+                opt = opt._replace(
+                    count=jax.device_put(opt.count, self._opt_sh.count))
         return TrainState(params, opt, 0)
 
     def restore_or_init(self, seed: int = 0) -> TrainState:
@@ -142,6 +152,15 @@ class Trainer:
                     state.step % run.checkpoint_every == 0:
                 self.save(state)
         return state
+
+    def step_hlo(self, state: TrainState) -> str:
+        """Optimized HLO of the compiled train step for ``state``'s next
+        batch: where its collectives and kernels can be read."""
+        batch = next(make_batch_iterator(self.cfg, self.run.shape, self.mesh,
+                                         seed=self.run.seed,
+                                         start_step=state.step))
+        return self._train_step.lower(
+            state.params, state.opt_state, batch).compile().as_text()
 
     # ------------------------------------------------------------------
     def save(self, state: TrainState, *, blocking: bool = False):

@@ -16,7 +16,7 @@ worse on held-out variants when the underlying truth is nonlinear).
 from __future__ import annotations
 
 from dataclasses import dataclass, field
-from typing import Dict, List, Tuple
+from typing import Dict, List, Optional, Tuple
 
 from repro.core.model import Model
 from repro.profiles.presets import DEFAULT_OUTPUT_FEATURE
@@ -45,8 +45,14 @@ class ZooEntry:
     name: str
     scope_rank: int
     expr: str
-    nonneg: bool = True
+    # the parameters that are costs, kept ≥ 0; None: every parameter
+    nonneg: Optional[Tuple[str, ...]] = None
     recoverable: Tuple[str, ...] = field(default=())
+
+    def __post_init__(self):
+        if self.nonneg is None:
+            object.__setattr__(self, "nonneg",
+                               tuple(self.model().param_names))
 
     def model(self, output_feature: str = DEFAULT_OUTPUT_FEATURE) -> Model:
         return Model(output_feature, self.expr)
@@ -79,7 +85,8 @@ OVL_FLOP_MEM = ZooEntry(
     scope_rank=2,
     expr=(f"overlap2(p_madd * f_op_float32_madd, p_mem * {_MEM}, p_edge) "
           "+ p_launch * f_sync_launch_kernel"),
-    nonneg=False,           # p_edge must float freely (paper §7.4 fits)
+    # the rates are costs; p_edge must float freely (paper §7.4 fits)
+    nonneg=("p_madd", "p_mem", "p_launch"),
     recoverable=("p_madd", "p_mem", "p_launch"),
 )
 

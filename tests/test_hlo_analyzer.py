@@ -23,8 +23,7 @@ def test_scan_flops_multiplied_by_trip_count():
     expect = 10 * 2 * 512 ** 3
     assert abs(c.flops - expect) / expect < 0.02
     # XLA's own analysis visits the body once → ~10× undercount
-    from repro.compat import jit_cost_analysis
-    xla = jit_cost_analysis(jax.jit(f).lower(s, s).compile())["flops"]
+    xla = jax.jit(f).lower(s, s).compile().cost_analysis()["flops"]
     assert xla < c.flops / 5
 
 
@@ -82,7 +81,7 @@ import jax, jax.numpy as jnp
 from jax.sharding import PartitionSpec as P, NamedSharding
 import sys
 sys.path.insert(0, "src")
-from repro.compat import make_mesh
+from repro.launch.mesh import make_mesh
 from repro.core.hlo import HloCostAnalyzer
 mesh = make_mesh((8,), ("d",))
 def f(x):

@@ -5,9 +5,9 @@ traffic match closed-form ground truth — with zero kernel executions.
 walks the kernel-body jaxpr abstractly, scales per-program counts by the
 grid size, and derives HBM↔VMEM traffic from each operand's BlockSpec
 (block shape × index-map refetch pattern × grid extent).  These tests pin
-the derived features against hand-computed formulas for the three
-canonical wrappers — matmul, stencil5, flash_attention — at ≥ 3 shapes
-each, entirely from ``ShapeDtypeStruct`` arguments (no device arrays
+the derived features against hand-computed formulas for five wrappers —
+matmul, stencil5, flash_attention, mamba2_ssd, slstm_cell — at ≥ 2
+shapes each, entirely from ``ShapeDtypeStruct`` arguments (no device arrays
 exist to execute), with kernel timing POISONED for good measure.
 
 A deliberately non-affine fixture (index map ``i * i``) pins the failure
@@ -34,6 +34,7 @@ from repro.core.counting import count_fn
 from repro.core.model import Model
 from repro.core.uipick import CountingTimer, MeasurementKernel
 from repro.kernels import ops
+from repro.kernels.slstm_cell import SEQ_TILE
 from repro.profiles import DeviceFingerprint, MachineProfile, ModelFit
 
 
@@ -107,8 +108,9 @@ def test_stencil5_counts_match_closed_form(M, N, bm, bn):
     fn = functools.partial(ops.stencil5, block_m=bm, block_n=bn)
     c = count_fn(fn, _f32(M, N))
     gm, gn = M // bm, N // bn
-    # haloed input block: (bm+2)×(bn+2) floats per grid program
-    assert c[BYTES_IN_FEATURE] == 4 * gm * gn * (bm + 2) * (bn + 2)
+    # haloed input window per grid program: the (bm+2)×(bn+2) halo widened
+    # to one (8, 128) tile of over-fetch so every DMA stays tile aligned
+    assert c[BYTES_IN_FEATURE] == 4 * gm * gn * (bm + 8) * (bn + 128)
     assert c[BYTES_OUT_FEATURE] == 4 * M * N
     # 5-point stencil: 4 adds + 1 scale per output element
     assert c["f_op_float32_add"] == 4 * M * N
@@ -137,6 +139,43 @@ def test_flash_attention_counts_match_closed_form(B, S, Hq, Hkv, D, bq, bk):
     assert c[BYTES_OUT_FEATURE] == 4 * B * Hq * S * D
     # exp over every bq×bk score tile + one per-row rescale exp
     assert c["f_op_float32_transc"] == B * Hq * nq * nk * (bq * bk + bq)
+
+
+@pytest.mark.parametrize("B,S,H,P,N,L", [
+    (2, 128, 4, 32, 16, 32),
+    (1, 256, 2, 64, 64, 64),
+])
+def test_mamba2_ssd_counts_match_closed_form(B, S, H, P, N, L):
+    fn = functools.partial(ops.mamba2_ssd, chunk=L)
+    c = count_fn(fn, _f32(B, S, H, P), _f32(B, S, H), _f32(B, S, H, N),
+                 _f32(B, S, H, N))
+    programs = B * H * (S // L)
+    # per token: x (P), B and C (N each), and the chunk-local log-decay
+    # twice — as the column and as the row of the decay matrix
+    assert c[BYTES_IN_FEATURE] == 4 * B * H * S * (P + 2 + 2 * N)
+    assert c[BYTES_OUT_FEATURE] == 4 * B * H * S * P
+    # per chunk: C·Bᵀ and its product with x (L² each), the inter-chunk
+    # read-out and the state update (L·N·P each)
+    assert c["f_op_float32_madd"] == programs * (
+        L * L * (N + P) + 2 * L * N * P)
+    assert c["f_sync_grid_programs"] == programs
+
+
+@pytest.mark.parametrize("B,S,H,dh", [
+    (2, 32, 4, 16),
+    (8, 64, 2, 32),
+])
+def test_slstm_cell_counts_match_closed_form(B, S, H, dh):
+    c = count_fn(ops.slstm_cell, _f32(B, S, 4, H, dh), _f32(H, dh, 4, dh),
+                 _f32(4, H, dh))
+    # g_in streams once; the recurrent weights and biases keep one block
+    # index for the whole grid, so they are fetched once and stay in VMEM
+    assert c[BYTES_IN_FEATURE] == 4 * (S * 4 * H * B * dh
+                                       + H * 4 * dh * dh + 4 * H * dh)
+    assert c[BYTES_OUT_FEATURE] == 4 * S * H * B * dh
+    # per step, head and gate: one [B, dh] @ [dh, dh] recurrent product
+    assert c["f_op_float32_madd"] == S * H * 4 * B * dh * dh
+    assert c["f_sync_grid_programs"] == S // SEQ_TILE
 
 
 # ---------------------------------------------------------------------------

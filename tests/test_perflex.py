@@ -69,7 +69,9 @@ def test_cond_counts_average():
 
 
 def test_collective_counts():
-    from repro.compat import P, make_mesh, shard_map
+    from jax.sharding import PartitionSpec as P
+
+    from repro.launch.mesh import make_mesh
 
     mesh = make_mesh((1,), ("i",))
 
@@ -77,7 +79,7 @@ def test_collective_counts():
         return jax.lax.psum(x, axis_name="i")
 
     c = count_fn(
-        shard_map(f, mesh=mesh, in_specs=P("i"), out_specs=P()),
+        jax.shard_map(f, mesh=mesh, in_specs=P("i"), out_specs=P()),
         jnp.zeros((8, 4)))
     assert c["f_coll_psum_bytes"] == 8 * 4 * 4
 
@@ -189,6 +191,20 @@ def test_nonneg_enforced():
     assert fit.params["p_a"] >= 0 and fit.params["p_b"] >= 0
 
 
+def test_nonneg_by_name_clamps_only_the_named_params():
+    # the same negative truth: clamping p_b alone leaves p_a free to fit
+    # its negative value, while p_b stays a cost
+    m = Model("f_wall_time_x", "p_a * f_x + p_b * f_y")
+    rows = [{"f_x": float(n), "f_y": float(n * n),
+             "f_wall_time_x": 2e-9 * n * n - 1e-9 * n}
+            for n in (8, 16, 32, 64)]
+    fit = fit_model(m, rows, nonneg=("p_b",))
+    assert fit.params["p_a"] == pytest.approx(-1e-9, rel=1e-3)
+    assert fit.params["p_b"] == pytest.approx(2e-9, rel=1e-3)
+    fit = fit_model(m, rows, nonneg=("p_a",))
+    assert fit.params["p_a"] >= 0
+
+
 def test_overlap_model_recovers_max_behavior():
     m = Model("f_wall_time_x",
               "overlap2(p_g * f_g, p_c * f_c, p_edge)")
@@ -245,6 +261,34 @@ def test_levenberg_marquardt_rosenbrock():
     p, rn, it, conv = levenberg_marquardt(resid, jnp.asarray([-1.2, 1.0]))
     assert rn < 1e-4
     assert np.allclose(np.asarray(p), [1.0, 1.0], atol=1e-2)
+
+
+def _dot_precisions(jaxpr):
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "dot_general":
+            yield eqn.params["precision"]
+        for val in eqn.params.values():
+            for sub in val if isinstance(val, (list, tuple)) else [val]:
+                inner = getattr(sub, "jaxpr", None)
+                if inner is not None:
+                    yield from _dot_precisions(getattr(inner, "jaxpr", inner))
+
+
+def test_lm_normal_equations_run_at_highest_precision():
+    """A TPU runs default-precision f32 matmuls as bf16 passes; the
+    solver's J^T J and J^T r must ask for full precision so a fit on the
+    chip matches the same fit on the host."""
+    from repro.core.calibrate import _lm_core
+
+    x = jnp.linspace(1.0, 2.0, 16)
+    jaxpr = jax.make_jaxpr(lambda p: _lm_core(
+        lambda q: q[0] * x + q[1] - (3.0 * x + 1.0), p, max_iters=5,
+        lam0=1e-3, lam_up=10.0, lam_down=0.3, tol=1e-12, nonneg=True,
+    ))(jnp.ones(2))
+    precisions = list(_dot_precisions(jaxpr.jaxpr))
+    assert len(precisions) >= 2
+    highest = (jax.lax.Precision.HIGHEST, jax.lax.Precision.HIGHEST)
+    assert all(p == highest for p in precisions), precisions
 
 
 # ---------------------------------------------------------------------------

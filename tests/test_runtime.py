@@ -151,3 +151,46 @@ def test_trainer_wires_slack_and_prediction_into_monitor(tmp_path):
     tr2 = Trainer(run, mesh=None)
     assert tr2.monitor.predicted_step_s is None
     assert tr2.monitor.expectation() is None
+
+
+# ---------------------------------------------------------------------------
+# sharded training on a (data 2, model 2) mesh of virtual CPU devices
+# ---------------------------------------------------------------------------
+
+_SHARDED = r"""
+import os, sys, tempfile
+os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+import jax
+sys.path.insert(0, "src")
+from repro.launch.mesh import make_host_mesh
+from repro.launch.train import run_config
+from repro.runtime import Trainer
+
+run = run_config("xlstm-125m", smoke=True, steps=3, seq_len=32, batch=4,
+                 ckpt_dir=tempfile.mkdtemp())
+trainer = Trainer(run, mesh=make_host_mesh(model=2))
+state = trainer.init_state(0)
+for p, m in zip(jax.tree.leaves(state.params),
+                jax.tree.leaves(state.opt_state.mu)):
+    assert m.sharding == p.sharding, (m.sharding, p.sharding)
+state = trainer.train(state, 3, log_every=0)
+assert not [r for r in trainer.metrics_log if r.get("event")], \
+    trainer.metrics_log
+print("TRACES", trainer._train_step._cache_size())
+"""
+
+
+def test_sharded_steps_keep_their_layout_and_compile_once():
+    """Optimizer moments are born with their parameter's sharding, and a
+    step returns its state in the layout the next step takes: three steps
+    run with one compiled program and no replayed step."""
+    import os
+    import subprocess
+    import sys
+
+    out = subprocess.run([sys.executable, "-c", _SHARDED],
+                         capture_output=True, text=True, cwd=os.getcwd(),
+                         env=dict(os.environ, JAX_PLATFORMS="cpu"),
+                         timeout=300)
+    assert out.returncode == 0, out.stderr[-3000:]
+    assert "TRACES 1" in out.stdout, out.stdout
