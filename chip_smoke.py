@@ -69,31 +69,6 @@ def say(phase: str, line: str) -> None:
     print(f"[{phase}] {line}", flush=True)
 
 
-class CompileMeter:
-    """Backend compile seconds and persistent-cache traffic, from JAX's
-    monitoring events.  A persistent-cache hit is recorded as a backend
-    compile lasting only the cache read, so a warm run's seconds drop."""
-
-    def __init__(self):
-        self.seconds = 0.0
-        self.compiles = 0
-        self.hits = 0
-        self.written = 0
-        jax.monitoring.register_event_duration_secs_listener(self._duration)
-        jax.monitoring.register_event_listener(self._event)
-
-    def _duration(self, event: str, duration: float, **_) -> None:
-        if event == "/jax/core/compile/backend_compile_duration":
-            self.seconds += duration
-            self.compiles += 1
-
-    def _event(self, event: str, **_) -> None:
-        if event == "/jax/compilation_cache/cache_hits":
-            self.hits += 1
-        elif event == "/jax/compilation_cache/cache_misses":
-            self.written += 1
-
-
 # ---------------------------------------------------------------------------
 # phases
 # ---------------------------------------------------------------------------
@@ -455,11 +430,10 @@ def main(argv=None) -> int:
         jax.config.update("jax_platforms", f"{platforms},cpu")
     devices = device_gate(count)
     sys.path.insert(0, str(ROOT / "src"))
-    from repro import compile_cache
+    from repro import compile_cache, spans
     from repro.studies import STUDY_TAGS
 
     say("cache", f"compilation cache at {compile_cache.enable()}")
-    meter = CompileMeter()
     RUN_DIR.mkdir(parents=True, exist_ok=True)
     t0 = time.perf_counter()
     if args.four_chips:
@@ -475,10 +449,16 @@ def main(argv=None) -> int:
         kernels(profile, kernel_cases(jax.random.PRNGKey(SEED)), fits)
         serve(profile)
         train(RUN_DIR)
-    say("compile", f"{meter.seconds:.1f} s backend compile over "
-                   f"{meter.compiles} compiles; persistent cache "
-                   f"{meter.hits} hits, {meter.written} written; "
-                   f"wall {time.perf_counter() - t0:.1f} s")
+    # every compile since repro.spans was imported: charged to a span, or
+    # to none (a thread with no open span)
+    alone = spans.unowned()
+    comp = {k: v + sum(t.get(k, 0) for t in spans.totals().values())
+            for k, v in alone.items()}
+    say("compile", f"{comp['compile_s']:.1f} s backend compile over "
+                   f"{comp['compiles']} compiles ({alone['compiles']} "
+                   f"outside any span); persistent cache "
+                   f"{comp['cache_hits']} hits, {comp['cache_misses']} "
+                   f"written; wall {time.perf_counter() - t0:.1f} s")
     dev = devices[0]
     print(json.dumps({"ok": True, "device": {
         "platform": dev.platform, "kind": dev.device_kind,

@@ -36,6 +36,7 @@ from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple, \
 import jax
 import jax.numpy as jnp
 
+from repro import spans
 from repro.api.errors import (
     PredictionError,
     scope_violation,
@@ -173,7 +174,9 @@ class PredictEngine:
         aligned = m.align(counts_rows)          # counts: absent == 0
         dt = _param_dtype()
         p_vec = jnp.asarray([mf.params[n] for n in m.param_names], dt)
-        parts = self._evaluator(m)(p_vec, jnp.asarray(aligned, dt))
+        # compile attrs (repro.spans) land here, one compile per row count
+        with spans.span("price.eval", rows=len(aligned)):
+            parts = self._evaluator(m)(p_vec, jnp.asarray(aligned, dt))
         with self._lock:
             self.eval_calls += 1
         preds = assemble_predictions(

@@ -36,6 +36,7 @@ from __future__ import annotations
 from pathlib import Path
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple, Union
 
+from repro import spans
 from repro.api.engine import DEFAULT_MODEL, PredictEngine
 from repro.api.errors import PredictionError
 from repro.api.prediction import Prediction
@@ -142,60 +143,62 @@ class PerfSession:
         during prediction.  ``save_to`` persists an on-demand calibration
         as a normal profile artifact.
         """
-        if isinstance(source, MachineProfile):
-            profile = source
-            _check_fingerprint(profile, expected_fingerprint)
-            return cls(profile,
-                       cache=_as_cache(cache, profile.fingerprint),
-                       timer=timer, engine=engine,
-                       calibration={"source": "profile", "timings": 0,
-                                    "retimed": 0})
-        if isinstance(source, (str, Path)):
-            fp = expected_fingerprint
-            if fp == "local":
-                fp = DeviceFingerprint.local()
-            profile = load_profile(source, expected_fingerprint=fp)
-            return cls(profile,
-                       cache=_as_cache(cache, profile.fingerprint),
-                       timer=timer, engine=engine,
-                       calibration={"source": f"profile:{source}",
-                                    "timings": 0, "retimed": 0})
+        with spans.span("price.open"):
+            if isinstance(source, MachineProfile):
+                profile = source
+                _check_fingerprint(profile, expected_fingerprint)
+                return cls(profile,
+                           cache=_as_cache(cache, profile.fingerprint),
+                           timer=timer, engine=engine,
+                           calibration={"source": "profile", "timings": 0,
+                                        "retimed": 0})
+            if isinstance(source, (str, Path)):
+                fp = expected_fingerprint
+                if fp == "local":
+                    fp = DeviceFingerprint.local()
+                profile = load_profile(source, expected_fingerprint=fp)
+                return cls(profile,
+                           cache=_as_cache(cache, profile.fingerprint),
+                           timer=timer, engine=engine,
+                           calibration={"source": f"profile:{source}",
+                                        "timings": 0, "retimed": 0})
 
-        # calibrate on demand (local hardware or an injectable device)
-        from repro.studies.study import run_study
-        from repro.studies.zoo import STUDY_TAGS
+            # calibrate on demand (local hardware or an injectable device)
+            from repro.studies.study import run_study
+            from repro.studies.zoo import STUDY_TAGS
 
-        if source is None:
-            fingerprint = DeviceFingerprint.local()
-            base_timer = timer
-        elif hasattr(source, "fingerprint") and hasattr(source, "timer"):
-            fingerprint = source.fingerprint
-            base_timer = timer or source.timer
-        else:
-            raise TypeError(
-                f"PerfSession.open expects a profile path, a "
-                f"MachineProfile, a device with .fingerprint/.timer, or "
-                f"None (this machine); got {type(source).__name__}")
-        counting = _as_counting_timer(base_timer)
-        mcache = _as_cache(cache, fingerprint)
-        if engine is None:
-            engine = CountEngine(
-                store=mcache.count_store if mcache is not None else None)
-        profile = run_study(
-            fingerprint=fingerprint, timer=counting, cache=mcache,
-            tags=tags or STUDY_TAGS, trials=trials,
-            holdout_fraction=holdout_fraction,
-            retime_rel_std=retime_rel_std, engine=engine)
-        if save_to is not None:
-            save_profile(profile, save_to)
-        return cls(profile, cache=mcache, timer=counting, engine=engine,
-                   calibration={
-                       "source": f"calibrated:{fingerprint.id}",
-                       "timings": counting.calls,
-                       "cache_hits": mcache.hits if mcache else 0,
-                       "count_traces": engine.trace_count,
-                       "retimed": len(getattr(profile, "retimed_rows", [])),
-                   })
+            if source is None:
+                fingerprint = DeviceFingerprint.local()
+                base_timer = timer
+            elif hasattr(source, "fingerprint") and hasattr(source, "timer"):
+                fingerprint = source.fingerprint
+                base_timer = timer or source.timer
+            else:
+                raise TypeError(
+                    f"PerfSession.open expects a profile path, a "
+                    f"MachineProfile, a device with .fingerprint/.timer, or "
+                    f"None (this machine); got {type(source).__name__}")
+            counting = _as_counting_timer(base_timer)
+            mcache = _as_cache(cache, fingerprint)
+            if engine is None:
+                engine = CountEngine(
+                    store=mcache.count_store if mcache is not None else None)
+            profile = run_study(
+                fingerprint=fingerprint, timer=counting, cache=mcache,
+                tags=tags or STUDY_TAGS, trials=trials,
+                holdout_fraction=holdout_fraction,
+                retime_rel_std=retime_rel_std, engine=engine)
+            if save_to is not None:
+                save_profile(profile, save_to)
+            return cls(profile, cache=mcache, timer=counting, engine=engine,
+                       calibration={
+                           "source": f"calibrated:{fingerprint.id}",
+                           "timings": counting.calls,
+                           "cache_hits": mcache.hits if mcache else 0,
+                           "count_traces": engine.trace_count,
+                           "retimed": len(getattr(profile, "retimed_rows",
+                                                  [])),
+                       })
 
     # ------------------------------------------------------------------
     # prediction
@@ -241,10 +244,11 @@ class PerfSession:
         items = list(items)
         if not items:
             return []
-        self.predict_engine.resolve(model)      # fail fast, pre-counting
-        kernel_names, counts_rows = self._count_items(items, names)
-        return self.predict_engine.predict_rows(
-            counts_rows, kernel_names, model=model, strict=strict)
+        with spans.span("price.batch", rows=len(items)):
+            self.predict_engine.resolve(model)  # fail fast, pre-counting
+            kernel_names, counts_rows = self._count_items(items, names)
+            return self.predict_engine.predict_rows(
+                counts_rows, kernel_names, model=model, strict=strict)
 
     def try_predict_batch(self, items: Sequence[PredictItem], *,
                           model: Optional[str] = None,
@@ -259,10 +263,11 @@ class PerfSession:
         items = list(items)
         if not items:
             return []
-        self.predict_engine.resolve(model)
-        kernel_names, counts_rows = self._count_items(items, names)
-        return self.predict_engine.try_predict_rows(
-            counts_rows, kernel_names, model=model, strict=strict)
+        with spans.span("price.batch", rows=len(items)):
+            self.predict_engine.resolve(model)
+            kernel_names, counts_rows = self._count_items(items, names)
+            return self.predict_engine.try_predict_rows(
+                counts_rows, kernel_names, model=model, strict=strict)
 
     def _count_items(self, items: Sequence[PredictItem],
                      names: Optional[Sequence[str]]

@@ -26,6 +26,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import spans
 from repro.core.model import (
     FeatureTableLike,
     Model,
@@ -335,22 +336,26 @@ def fit_models(
     model name → nonnegativity constraint, as :func:`fit_model` takes it
     (default True, the paper's cost-explanatory setting).
     """
-    table = as_feature_table(feature_table)
-    nonneg = dict(nonneg or {})
-    fits: Dict[str, FitResult] = {}
-    ladder: Dict[str, float] = {}
-    for name, model in models.items():
-        p0 = {n: ladder[n] for n in model.param_names if n in ladder} \
-            if warm_start and ladder else None
-        fit = fit_model(model, table, scale_by_output=scale_by_output,
-                        nonneg=nonneg.get(name, True), seeds=seeds,
-                        p0=p0, **solver_opts)
-        fits[name] = fit
-        # carry only positive estimates forward: a rate clamped to 0 by a
-        # narrow model is a worse start (and a degenerate LM scale) than an
-        # earlier model's coarse positive estimate
-        ladder.update({k: v for k, v in fit.params.items() if v > 0})
-    return fits
+    with spans.span("solve.fit"):
+        table = as_feature_table(feature_table)
+        nonneg = dict(nonneg or {})
+        fits: Dict[str, FitResult] = {}
+        ladder: Dict[str, float] = {}
+        for name, model in models.items():
+            p0 = {n: ladder[n] for n in model.param_names if n in ladder} \
+                if warm_start and ladder else None
+            with spans.span("solve.rung", model=name) as s:
+                fit = fit_model(model, table, scale_by_output=scale_by_output,
+                                nonneg=nonneg.get(name, True), seeds=seeds,
+                                p0=p0, **solver_opts)
+                s.attrs["iterations"] = fit.iterations
+                s.attrs["converged"] = fit.converged
+            fits[name] = fit
+            # carry only positive estimates forward: a rate clamped to 0 by a
+            # narrow model is a worse start (and a degenerate LM scale) than an
+            # earlier model's coarse positive estimate
+            ladder.update({k: v for k, v in fit.params.items() if v > 0})
+        return fits
 
 
 def relative_errors(model: Model, params: Mapping[str, float],

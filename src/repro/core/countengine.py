@@ -45,6 +45,7 @@ from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from repro import spans
 from repro.core.counting import (
     FeatureCounts,
     SymbolicCounts,
@@ -338,6 +339,32 @@ _STORE_ENTRY_NAME = re.compile(r"[0-9a-f]{64}\.json")
 # ---------------------------------------------------------------------------
 
 
+class _TimedLock:
+    """A re-entrant lock that keeps how long its holder's outermost
+    acquire waited (``wait_s``), for the first ``count.trace`` span under
+    that acquire.  The two fields are only touched by the thread that
+    holds the lock."""
+
+    __slots__ = ("_lock", "_depth", "wait_s")
+
+    def __init__(self):
+        self._lock = threading.RLock()
+        self._depth = 0
+        self.wait_s = 0.0
+
+    def __enter__(self) -> "_TimedLock":
+        t0 = time.perf_counter()
+        self._lock.acquire()
+        if not self._depth:
+            self.wait_s = time.perf_counter() - t0
+        self._depth += 1
+        return self
+
+    def __exit__(self, *exc) -> None:
+        self._depth -= 1
+        self._lock.release()
+
+
 class CountEngine:
     """Amortized feature counting with an observable cost model.
 
@@ -373,12 +400,17 @@ class CountEngine:
         # re-entrant: counts_batch holds it while delegating to counts_for
         # and symbolic.  Held across cold traces on purpose — serializing
         # the trace is what guarantees one trace per key under contention.
-        self._lock = threading.RLock()
+        self._lock = _TimedLock()
 
     # -- tracing seam (every make_jaxpr in the engine goes through here) --
     def _trace(self, fn: Callable, args: Sequence[Any]) -> FeatureCounts:
+        """Called with the lock held; the span ``count.trace`` carries how
+        long this thread waited for it (``lock_wait_s``), on the first
+        trace under one acquire only, so that sums count each wait once."""
         self.trace_count += 1
-        return count_fn(fn, *args)
+        wait, self._lock.wait_s = self._lock.wait_s, 0.0
+        with spans.span("count.trace", lock_wait_s=wait):
+            return count_fn(fn, *args)
 
     # -- concrete counts ---------------------------------------------------
     def counts_for(self, kernel: MeasurementKernel, *,

@@ -22,6 +22,7 @@ import inspect
 import itertools
 import json
 import time
+import types
 import warnings
 from dataclasses import dataclass, field
 from typing import Any, Callable, Dict, FrozenSet, Iterable, List, Mapping, Optional, Sequence, Tuple, Union
@@ -30,6 +31,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import spans
 from repro.core.counting import FeatureCounts, count_fn
 from repro.core.model import FeatureTable
 from repro.deprecation import warn_once
@@ -167,19 +169,30 @@ class MeasurementKernel:
 
     def time_stats(self, *, trials: int = 20, warmup: int = 3
                    ) -> TimingStats:
-        """One timing pass reported with its spread (median/std/min)."""
-        jf = self.jitted()
-        args = self.make_args()
+        """One timing pass reported with its spread (median/std/min).
+
+        Spans: ``measure.args`` builds the arguments; ``measure.load``
+        gets the program (trace, lower, and compile or read it from the
+        persistent cache) and makes the first warm-up call, blocked until
+        ready; ``measure.time`` makes the other warm-up calls and the
+        timed trials."""
+        with spans.span("measure.args"):
+            args = self.make_args()
         out = None
-        for _ in range(warmup):
-            out = jf(*args)
-        if out is not None:
-            jax.block_until_ready(out)
-        ts = []
-        for _ in range(trials):
-            t0 = time.perf_counter()
-            jax.block_until_ready(jf(*args))
-            ts.append(time.perf_counter() - t0)
+        with spans.span("measure.load"):
+            jf = self.jitted()
+            if warmup:
+                jax.block_until_ready(jf(*args))
+        with spans.span("measure.time", trials=trials):
+            for _ in range(warmup - 1):
+                out = jf(*args)
+            if out is not None:
+                jax.block_until_ready(out)
+            ts = []
+            for _ in range(trials):
+                t0 = time.perf_counter()
+                jax.block_until_ready(jf(*args))
+                ts.append(time.perf_counter() - t0)
         return TimingStats(median=float(np.median(ts)),
                            std=float(np.std(ts)), min=float(np.min(ts)))
 
@@ -250,6 +263,11 @@ class Generator:
                 continue
             if not kernel.code_sig:
                 kernel.code_sig = self.code_sig
+            if isinstance(kernel.fn, types.FunctionType):
+                # its program then reads as the generator in a device
+                # trace (``jit_matmul_sq``), not as ``fn``, the name every
+                # generator gives its callable
+                kernel.fn.__name__ = self.name
             if self.family is not None and kernel.family is None:
                 fixed_key = tuple(sorted(
                     (a, v) for a, v in kw.items()
@@ -418,85 +436,112 @@ def gather_feature_table(
     ``retimed_rows`` so callers (CLI, ``PerfSession``) can surface how
     much of the battery was unstable.  Note this intentionally trades the
     warm-cache zero-timing guarantee for timing quality on noisy rows.
+
+    Spans (:mod:`repro.spans`): ``measure.gather`` around the whole;
+    inside it ``measure.cache`` around the lookups (``hits``,
+    ``misses``) and each put, ``count.batch`` around the counting
+    (``rows``, and with an engine ``traces`` and ``families_built``),
+    and one ``measure.kernel`` per row.
     """
-    features = list(features)
-    timer = timer or default_timer
-    wall_cols = [j for j, f in enumerate(features)
-                 if f.startswith("f_wall_time")]
-    count_cols = [(j, f) for j, f in enumerate(features)
-                  if not f.startswith("f_wall_time")]
-    values = np.zeros((len(kernels), len(features)), np.float64)
-    row_noise: Dict[str, Dict[str, float]] = {}
-    retimed: List[str] = []
-    entries = [cache.get(k, trials) if cache is not None else None
-               for k in kernels]
-    # counts for every cache-missing row, resolved up front: the engine
-    # batches symbolic families across the whole battery (vectorized
-    # polynomial evaluation), so this is one pass, not one per row
-    need = [i for i, e in enumerate(entries) if e is None]
-    if engine is not None and need:
-        fresh_counts = dict(zip(
-            need, engine.counts_batch([kernels[i] for i in need])))
-    else:
-        fresh_counts = {i: kernels[i].counts() for i in need}
-    # duplicate kernels in ONE cold gather (same name/sizes/code identity)
-    # must be measured once — the pre-resolved entries above can't see the
-    # put an earlier iteration performed, so track in-gather results here
-    local: Dict[Tuple, Tuple] = {}
-    for i, k in enumerate(kernels):
-        entry = entries[i]
-        kid = (k.name, tuple(sorted(k.sizes.items())), k.code_sig)
-        if entry is None and kid in local:
-            counts, wall, stats = local[kid]
-            for j, f in count_cols:
-                values[i, j] = counts[f]
-            for j in wall_cols:
-                values[i, j] = wall
-            if stats is not None and (stats.std is not None
-                                      or stats.min is not None):
-                row_noise[k.name] = stats.to_dict()
-            continue
-        stats: Optional[TimingStats] = None
-        if entry is not None:
-            counts, wall = entry.counts, entry.wall_time
-            stats = entry.noise
-            if wall_cols and wall is None:
-                # entry was gathered counts-only; backfill the timing
-                stats = TimingStats.coerce(timer(k, trials))
-                wall = stats.median
+    with spans.span("measure.gather"):
+        features = list(features)
+        timer = timer or default_timer
+        wall_cols = [j for j, f in enumerate(features)
+                     if f.startswith("f_wall_time")]
+        count_cols = [(j, f) for j, f in enumerate(features)
+                      if not f.startswith("f_wall_time")]
+        values = np.zeros((len(kernels), len(features)), np.float64)
+        row_noise: Dict[str, Dict[str, float]] = {}
+        retimed: List[str] = []
+        entries: List[Any] = [None] * len(kernels)
+        if cache is not None:
+            with spans.span("measure.cache") as s:
+                entries = [cache.get(k, trials) for k in kernels]
+                s.attrs["hits"] = sum(e is not None for e in entries)
+                s.attrs["misses"] = len(entries) - s.attrs["hits"]
+
+        def put(k, wall, counts, stats) -> None:
+            with spans.span("measure.cache", puts=1):
                 cache.put(k, trials, wall, counts, noise=stats)
-        else:
-            counts = fresh_counts[i]
-            if wall_cols:
-                stats = TimingStats.coerce(timer(k, trials))
-                wall = stats.median
-            else:
-                wall = None
-            if cache is not None:
-                cache.put(k, trials, wall, counts, noise=stats)
-        if (retime_rel_std is not None and wall_cols and stats is not None
-                and stats.std is not None
-                and _rel_std(stats) > retime_rel_std):
-            # noisy row: one extra pass; the steadier measurement wins
-            fresh = TimingStats.coerce(timer(k, trials))
-            retimed.append(k.name)
-            if _rel_std(fresh) < _rel_std(stats):
-                stats, wall = fresh, fresh.median
-                if cache is not None:
-                    cache.put(k, trials, wall, counts, noise=stats)
-        if stats is not None and (stats.std is not None
-                                  or stats.min is not None):
-            row_noise[k.name] = stats.to_dict()
-        if entries[i] is None:
-            local[kid] = (counts, wall, stats)
-        for j, f in count_cols:
-            values[i, j] = counts[f]
-        for j in wall_cols:
-            values[i, j] = wall
-    table = FeatureTable(features, values, [k.name for k in kernels],
-                         row_noise)
-    table.retimed_rows = retimed
-    return table
+
+        # counts for every cache-missing row, resolved up front: the engine
+        # batches symbolic families across the whole battery (vectorized
+        # polynomial evaluation), so this is one pass, not one per row
+        need = [i for i, e in enumerate(entries) if e is None]
+        fresh_counts: Dict[int, FeatureCounts] = {}
+        if need:
+            with spans.span("count.batch", rows=len(need)) as s:
+                if engine is not None:
+                    before = engine.stats()
+                    fresh_counts = dict(zip(
+                        need, engine.counts_batch([kernels[i] for i in need])))
+                    after = engine.stats()
+                    s.attrs["traces"] = (after["trace_count"]
+                                         - before["trace_count"])
+                    s.attrs["families_built"] = (after["families"]
+                                                 - before["families"])
+                else:
+                    fresh_counts = {i: kernels[i].counts() for i in need}
+        # duplicate kernels in ONE cold gather (same name/sizes/code
+        # identity) must be measured once — the pre-resolved entries above
+        # can't see the put an earlier iteration performed, so track
+        # in-gather results here
+        local: Dict[Tuple, Tuple] = {}
+        for i, k in enumerate(kernels):
+            with spans.span("measure.kernel", kernel=k.name):
+                entry = entries[i]
+                kid = (k.name, tuple(sorted(k.sizes.items())), k.code_sig)
+                if entry is None and kid in local:
+                    counts, wall, stats = local[kid]
+                    for j, f in count_cols:
+                        values[i, j] = counts[f]
+                    for j in wall_cols:
+                        values[i, j] = wall
+                    if stats is not None and (stats.std is not None
+                                              or stats.min is not None):
+                        row_noise[k.name] = stats.to_dict()
+                    continue
+                stats: Optional[TimingStats] = None
+                if entry is not None:
+                    counts, wall = entry.counts, entry.wall_time
+                    stats = entry.noise
+                    if wall_cols and wall is None:
+                        # entry was gathered counts-only; backfill the timing
+                        stats = TimingStats.coerce(timer(k, trials))
+                        wall = stats.median
+                        put(k, wall, counts, stats)
+                else:
+                    counts = fresh_counts[i]
+                    if wall_cols:
+                        stats = TimingStats.coerce(timer(k, trials))
+                        wall = stats.median
+                    else:
+                        wall = None
+                    if cache is not None:
+                        put(k, wall, counts, stats)
+                if (retime_rel_std is not None and wall_cols
+                        and stats is not None and stats.std is not None
+                        and _rel_std(stats) > retime_rel_std):
+                    # noisy row: one extra pass; the steadier measurement wins
+                    fresh = TimingStats.coerce(timer(k, trials))
+                    retimed.append(k.name)
+                    if _rel_std(fresh) < _rel_std(stats):
+                        stats, wall = fresh, fresh.median
+                        if cache is not None:
+                            put(k, wall, counts, stats)
+                if stats is not None and (stats.std is not None
+                                          or stats.min is not None):
+                    row_noise[k.name] = stats.to_dict()
+                if entries[i] is None:
+                    local[kid] = (counts, wall, stats)
+                for j, f in count_cols:
+                    values[i, j] = counts[f]
+                for j in wall_cols:
+                    values[i, j] = wall
+        table = FeatureTable(features, values, [k.name for k in kernels],
+                             row_noise)
+        table.retimed_rows = retimed
+        return table
 
 
 def gather_feature_values(

@@ -47,6 +47,7 @@ import sys
 from pathlib import Path
 from typing import List, Optional
 
+from repro import spans
 from repro.core.calibrate import fit_model
 from repro.core.model import Model
 from repro.core.uipick import (
@@ -146,7 +147,15 @@ def _noise_line(table) -> str:
 
 
 def _calibrate(argv: Optional[List[str]]) -> int:
+    """One calibration: the root span ``calibrate.profile`` (attrs
+    ``trials``, ``kernels``) around everything it does."""
+    with spans.span("calibrate.profile") as root:
+        return _calibrate_in(argv, root.attrs)
+
+
+def _calibrate_in(argv: Optional[List[str]], attrs) -> int:
     args = build_parser().parse_args(argv)
+    attrs["trials"] = args.trials
 
     if args.synthetic:
         from repro.testing.synthdev import fleet_device
@@ -193,7 +202,9 @@ def _calibrate(argv: Optional[List[str]]) -> int:
         except StudyError as e:
             print(f"[calibrate] {e}", file=sys.stderr)
             return 2
-        save_profile(profile, args.out)
+        attrs["kernels"] = len(profile.kernel_names)
+        with spans.span("calibrate.save"):
+            save_profile(profile, args.out)
         _retime_line(args, profile.retimed_rows)
         print(f"[calibrate] {_noise_line(profile.holdout)}")
         for name, mf in sorted(profile.fits.items()):
@@ -205,8 +216,10 @@ def _calibrate(argv: Optional[List[str]]) -> int:
                              else BASE_MODEL_EXPR)
         tags = args.tags or (SMOKE_TAGS if args.smoke else CALIBRATION_TAGS)
         model = Model(args.output_feature, expr)
-        kernels = KernelCollection(ALL_GENERATORS).generate_kernels(
-            tags, generator_match_cond=_MATCH[args.match])
+        with spans.span("calibrate.battery"):
+            kernels = KernelCollection(ALL_GENERATORS).generate_kernels(
+                tags, generator_match_cond=_MATCH[args.match])
+        attrs["kernels"] = len(kernels)
         if not kernels:
             print(f"no measurement kernels match tags {tags!r}",
                   file=sys.stderr)
@@ -225,7 +238,8 @@ def _calibrate(argv: Optional[List[str]]) -> int:
             fits={args.name: ModelFit.from_fit(model, fit)},
             trials=args.trials,
             kernel_names=[k.name for k in kernels])
-        save_profile(profile, args.out)
+        with spans.span("calibrate.save"):
+            save_profile(profile, args.out)
         print(f"[calibrate] {_noise_line(table)}")
         print(f"[calibrate] fit residual={fit.residual_norm:.3g} "
               f"converged={fit.converged} params={fit.params}")

@@ -29,6 +29,7 @@ from concurrent.futures import Future
 from dataclasses import dataclass, field
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
+from repro import spans
 from repro.api import PerfSession, Prediction, PredictionError
 
 
@@ -39,6 +40,7 @@ class _Request:
     model: Optional[str]
     strict: bool
     future: "Future" = field(default_factory=Future)
+    submitted: float = field(default_factory=time.perf_counter)
 
 
 class BatcherClosed(RuntimeError):
@@ -161,6 +163,13 @@ class CoalescingBatcher:
                 self._execute(batch)
 
     def _execute(self, batch: Sequence[_Request]) -> None:
+        """One drained batch, as the span ``serve.batch`` (``size``, and
+        ``queue_wait_s``: the oldest request's wait from ``submit``)."""
+        wait = time.perf_counter() - min(r.submitted for r in batch)
+        with spans.span("serve.batch", size=len(batch), queue_wait_s=wait):
+            self._execute_groups(batch)
+
+    def _execute_groups(self, batch: Sequence[_Request]) -> None:
         # group by (model, strict): each group is one batched call
         groups: Dict[Tuple[Optional[str], bool], List[_Request]] = {}
         for req in batch:

@@ -13,7 +13,10 @@ Request/response protocol (JSON over stdlib HTTP — no third-party deps):
 * ``GET /stats`` — the daemon's observability ledger: kernel timings
   performed (must stay 0 on the serving path), compiled
   ``batched_breakdown`` dispatches, jit traces, count lookups, batcher
-  coalescing counters, and pool opens/evictions.
+  coalescing counters, pool opens/evictions, and per span name
+  (:mod:`repro.spans`) the spans finished, their seconds and the sums of
+  their numeric attrs (``serve.batch``: ``size``, ``queue_wait_s``;
+  ``count.trace``: ``lock_wait_s``; any span: ``compiles``).
 * ``GET /healthz`` — liveness.
 * ``POST /shutdown`` — clean stop (drains in-flight batches).
 
@@ -43,6 +46,7 @@ import threading
 from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 from typing import Any, Dict, Optional, Tuple
 
+from repro import spans
 from repro.api import PerfSession, Prediction, PredictionError
 from repro.serving.coalesce import CoalescingBatcher
 from repro.serving.pool import SessionPool
@@ -232,6 +236,7 @@ class PredictionDaemon:
             "count_traces": eng.trace_count,
             "batcher": self.batcher.stats(),
             "pool": self.pool.stats(),
+            "spans": spans.totals(),
         }
         if self.router is not None:
             out["fleet"] = self.router.stats()

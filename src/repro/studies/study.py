@@ -27,6 +27,7 @@ from dataclasses import dataclass, field
 from pathlib import Path
 from typing import Any, Callable, Dict, List, Optional, Sequence
 
+from repro import spans
 from repro.core.calibrate import fit_models, gmre_of, relative_errors
 from repro.core.model import FeatureTable
 from repro.core.uipick import (
@@ -100,8 +101,9 @@ def run_study(
             f"holdout_fraction must be in (0, 1), got {holdout_fraction}; "
             f"a study without held-out rows cannot report accuracy, and "
             f"holding out (nearly) everything leaves nothing to fit")
-    kernels = KernelCollection(ALL_GENERATORS).generate_kernels(
-        list(tags), generator_match_cond=match)
+    with spans.span("calibrate.battery"):
+        kernels = KernelCollection(ALL_GENERATORS).generate_kernels(
+            list(tags), generator_match_cond=match)
     if len(kernels) < 2:
         raise StudyError(
             f"study battery matched {len(kernels)} kernels for tags "
@@ -130,13 +132,14 @@ def run_study(
         from repro.analysis.identifiability import analyze_model
 
         structural = []
-        for name in sorted(models):
-            m = models[name]
-            structural += [
-                d for d in analyze_model(
-                    m, m.align(train, missing="zero"),
-                    f"model:{name}[train]")
-                if d.severity == "error"]
+        with spans.span("solve.identify", models=len(models)):
+            for name in sorted(models):
+                m = models[name]
+                structural += [
+                    d for d in analyze_model(
+                        m, m.align(train, missing="zero"),
+                        f"model:{name}[train]")
+                    if d.severity == "error"]
         if structural:
             raise StudyError(
                 "the train split cannot identify every zoo rung's "

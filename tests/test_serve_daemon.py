@@ -28,6 +28,7 @@ from pathlib import Path
 import jax.numpy as jnp
 import pytest
 
+from repro import spans
 from repro.api import PerfSession, Prediction, PredictionError
 from repro.core.calibrate import FitResult
 from repro.core.countengine import CountEngine
@@ -349,6 +350,34 @@ def test_daemon_http_error_codes(daemon):
     assert status == 422
     (v,) = body["violations"]
     assert v["features"] == ["f_op_float32_transc"]
+
+
+def test_daemon_stats_carry_spans(daemon):
+    """``GET /stats`` serves the span totals, and the drained batch is a
+    ``serve.batch`` root whose pricing spans hang under it."""
+    t0 = time.perf_counter_ns()
+    status, _ = _post(f"{daemon.url}/predict", {"kernel": "t1"})
+    assert status == 200
+    # the answer is sent from inside the batch's span: wait for it to end
+    deadline = time.monotonic() + 30
+    while not [s for s in spans.between(t0, time.perf_counter_ns())
+               if s.name == "serve.batch"]:
+        assert time.monotonic() < deadline, "serve.batch never ended"
+        time.sleep(0.005)
+    with urllib.request.urlopen(f"{daemon.url}/stats", timeout=30) as r:
+        stats = json.loads(r.read())
+    for name in ("serve.batch", "price.batch", "price.eval"):
+        got = stats["spans"][name]
+        assert got["count"] >= 1 and got["seconds"] > 0
+    served = stats["spans"]["serve.batch"]
+    assert served["size"] >= 1 and served["queue_wait_s"] >= 0
+    done = spans.between(t0, time.perf_counter_ns())
+    (batch,) = [s for s in done if s.name == "serve.batch"]
+    assert batch.parent == 0 and batch.attrs["size"] == 1
+    assert batch.attrs["queue_wait_s"] >= 0
+    (price,) = [s for s in done if s.name == "price.batch"]
+    assert price.parent == batch.id and price.root == batch.id
+    assert price.attrs["rows"] == 1
 
 
 def test_daemon_stats_and_shutdown_routes(daemon):
