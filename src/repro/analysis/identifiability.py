@@ -12,7 +12,12 @@ it buys a fit whose parameters are arbitrary along the null space.
 The analysis evaluates ``J`` at a few deterministic parameter points (a
 linear model's Jacobian is constant; a nonlinear one — ``overlap2`` and
 friends — is not, and a rank defect at ALL probe points is structural,
-not an unlucky linearization), column-normalizes, and reads the SVD:
+not an unlucky linearization), column-normalizes, and reads the SVD.
+The Jacobians at every probe point come from one compiled call per model
+(:meth:`Model.param_jacobian`), whose program is cached by the model's
+content signature: a study re-creates its zoo rungs every profile and
+re-analyses each profile's own train split, and pays the trace and the
+compile once per process.  The SVD and the norms are numpy:
 
 * ``underdetermined-battery`` (error) — fewer battery rows than
   parameters: rank-deficient regardless of content;
@@ -96,8 +101,8 @@ def analyze_model(model: Model, features: np.ndarray, location: str
         return out
 
     # design matrix: parameter Jacobians stacked over probe points
-    J = np.concatenate([model.param_jacobian(p, F)
-                        for p in _probe_points(len(params))], axis=0)
+    J = np.concatenate(model.param_jacobian(_probe_points(len(params)), F),
+                       axis=0)
     J = np.nan_to_num(J, nan=0.0, posinf=0.0, neginf=0.0)
 
     norms = np.linalg.norm(J, axis=0)

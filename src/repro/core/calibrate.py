@@ -183,34 +183,16 @@ def levenberg_marquardt(
 # ---------------------------------------------------------------------------
 
 
-# Process-wide solver cache keyed by model *content signature* + solver
-# options.  Model instances cache their compiled solver locally, but a
-# multi-model study recreates Model objects (zoo registry, profile loads)
-# — identical (output feature, expr) must not pay re-tracing, so the trace
-# is shared across instances here.  Sound because the signature pins the
-# exact expression, hence identical param/feature orderings and identical
-# computations.  FIFO-bounded: each compiled closure pins a Model for as
-# long as it is cached, and a long-lived process sweeping many distinct
-# expressions must not grow without bound.
-_SHARED_SOLVER_CACHE: Dict[tuple, Callable] = {}
-_SHARED_SOLVER_CACHE_MAX = 64
-
-
 def _batch_solver(model: Model, *, nonneg: Union[bool, Tuple[bool, ...]],
                   max_iters: int, lam0: float,
                   lam_up: float, lam_down: float, tol: float) -> Callable:
     """Compiled ``(F, target, starts) -> best (p, cost, it, conv)`` solver;
-    cached on the model AND in the process-wide signature-keyed cache so
-    repeated calibrations — including of re-created equal models — re-use
-    the trace (jit itself re-specializes on new table shapes)."""
+    cached by :meth:`Model.compiled`, so repeated calibrations — including
+    of re-created equal models — re-use the trace (jit itself
+    re-specializes on new table shapes)."""
     key = ("lm_batch", nonneg, max_iters, lam0, lam_up, lam_down, tol)
-    solver = model._solver_cache.get(key)
-    if solver is None:
-        solver = _SHARED_SOLVER_CACHE.get((model.signature(),) + key)
-        if solver is not None:
-            model._solver_cache[key] = solver
-    if solver is None:
 
+    def build():
         @jax.jit
         def solver(F, target, starts, scale):
             """``starts`` are in scale-normalized units: the model sees
@@ -230,11 +212,9 @@ def _batch_solver(model: Model, *, nonneg: Union[bool, Tuple[bool, ...]],
             best = jnp.argmin(cost)
             return p[best] * scale, cost[best], it[best], conv[best]
 
-        model._solver_cache[key] = solver
-        while len(_SHARED_SOLVER_CACHE) >= _SHARED_SOLVER_CACHE_MAX:
-            _SHARED_SOLVER_CACHE.pop(next(iter(_SHARED_SOLVER_CACHE)))
-        _SHARED_SOLVER_CACHE[(model.signature(),) + key] = solver
-    return solver
+        return solver
+
+    return model.compiled(key, build)[0]
 
 
 def _multi_starts(p_init: jax.Array, names: Sequence[str], seeds: int

@@ -28,6 +28,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import spans
 from repro.core import overlap as _ovl
 from repro.core.counting import FeatureCounts
 from repro.deprecation import warn_once
@@ -103,6 +104,20 @@ def _compile_node(node: ast.expr):
 
 def _param_dtype():
     return jnp.float64 if jax.config.read("jax_enable_x64") else jnp.float32
+
+
+# Process-wide cache of the compiled programs built from a model (the
+# Jacobian of :meth:`Model.param_jacobian`, calibration's LM solver),
+# keyed by model *content signature* + program key.  Each Model caches its
+# programs locally, but a study recreates Model objects every profile (zoo
+# registry, profile loads) — identical (output feature, expr) must not pay
+# re-tracing, so programs are shared across instances here.  Sound because
+# the signature pins the exact expression, hence identical param/feature
+# orderings and identical computations.  FIFO-bounded: each compiled
+# closure pins a Model for as long as it is cached, and a long-lived
+# process sweeping many distinct expressions must not grow without bound.
+_SHARED_COMPILED: Dict[tuple, Callable] = {}
+_SHARED_COMPILED_MAX = 128
 
 
 # ---------------------------------------------------------------------------
@@ -251,8 +266,8 @@ class Model:
             return eval(code, {"__builtins__": {}}, {**_FUNCS, **env})
 
         self._eval = evaluator
-        # jitted-solver cache, keyed by solver options (repro.core.calibrate)
-        self._solver_cache: Dict[tuple, Callable] = {}
+        # compiled programs of this model, keyed as in :meth:`compiled`
+        self._compiled: Dict[tuple, Callable] = {}
         # per-term breakdown plan, built lazily on first breakdown request
         self._breakdown_plan: Optional[List[tuple]] = None
 
@@ -360,19 +375,49 @@ class Model:
                     out[p] |= feats
         return {p: sorted(fs) for p, fs in out.items()}
 
-    def param_jacobian(self, p_vec: jax.Array, features: jax.Array
+    def compiled(self, key: tuple, build: Callable[[], Callable]
+                 ) -> Tuple[Callable, bool]:
+        """``(program, built)``: the program ``build()`` makes for this
+        model under ``key``, taken from this instance, else from the
+        process-wide cache of equal models (same :meth:`signature`), else
+        built now and put in both.  ``built`` says which; ``jax.jit``
+        itself still re-specializes on new argument shapes."""
+        fn = self._compiled.get(key)
+        if fn is not None:
+            return fn, False
+        shared_key = (self.signature(),) + key
+        fn = _SHARED_COMPILED.get(shared_key)
+        built = fn is None
+        if built:
+            fn = build()
+            while len(_SHARED_COMPILED) >= _SHARED_COMPILED_MAX:
+                _SHARED_COMPILED.pop(next(iter(_SHARED_COMPILED)))
+            _SHARED_COMPILED[shared_key] = fn
+        self._compiled[key] = fn
+        return fn, built
+
+    def param_jacobian(self, points: np.ndarray, features: np.ndarray
                        ) -> np.ndarray:
-        """``∂ prediction / ∂ parameters`` at one parameter point:
-        ``[n_rows, n_params]`` float64, rows aligned with ``features``
-        (same column conventions as :meth:`batched_eval`), columns ordered
-        as ``self.param_names``.  This IS the least-squares design matrix
-        of a fit linearized at ``p_vec`` — exact for linear models at any
-        point — and the raw material of the static identifiability
-        analysis (``repro.analysis.identifiability``)."""
-        dt = _param_dtype()
-        F = jnp.asarray(features, dt)
-        J = jax.jacfwd(lambda p: self.batched_eval(p, F))(
-            jnp.asarray(p_vec, dt))
+        """``∂ prediction / ∂ parameters`` at each of ``k`` parameter
+        points (``points`` is ``[k, n_params]``): ``[k, n_rows, n_params]``
+        float64, rows aligned with ``features`` (same column conventions
+        as :meth:`batched_eval`), columns ordered as ``self.param_names``.
+        Block ``i`` IS the least-squares design matrix of a fit linearized
+        at ``points[i]`` — exact for linear models at any point — and the
+        raw material of the static identifiability analysis
+        (``repro.analysis.identifiability``).
+
+        One compiled ``jit(vmap(jacfwd(batched_eval)))`` call and one host
+        fetch, evaluated in :func:`_param_dtype`.  The program is cached by
+        :meth:`compiled`, so equal models re-created each profile reuse
+        it; each reuse adds 1 to the ``reused`` attr of the innermost open
+        span (``repro.spans``)."""
+        fn, built = self.compiled(("param_jacobian",), lambda: jax.jit(
+            jax.vmap(jax.jacfwd(self.batched_eval), in_axes=(0, None))))
+        if not built:
+            spans.add("reused")
+        dt = np.dtype(_param_dtype())
+        J = fn(np.asarray(points, dt), np.asarray(features, dt))
         return np.asarray(J, np.float64)
 
     def batched_eval(self, p_vec: jax.Array, features: jax.Array

@@ -14,12 +14,14 @@
 
 Counts ride as attrs of the span at their boundary (``rows=``,
 ``traces=``); ``with span(...) as s: s.attrs["hits"] = n`` sets one
-after the work.  JAX's compile events add ``compiles`` (backend
-compiles, persistent-cache reads included), ``cache_hits`` (those read
-from the persistent cache), ``cache_misses`` (those written to it) and
-``compile_s`` to the innermost open span of the thread they fire on, so
-a compile is charged to the step that caused it; a compile on a thread
-with no open span is counted in :func:`unowned`.
+after the work, and ``add("reused")`` counts one into the innermost
+open span from code below the layer that opened it.  JAX's compile
+events add ``compiles`` (backend compiles, persistent-cache reads
+included), ``cache_hits`` (those read from the persistent cache),
+``cache_misses`` (those written to it) and ``compile_s`` to the
+innermost open span of the thread they fire on, so a compile is charged
+to the step that caused it; a compile on a thread with no open span is
+counted in :func:`unowned`.
 
 The recorder is always on, so it stays cheap: a few microseconds a
 span, no span inside a loop of timed calls, and no formatting of attrs
@@ -147,6 +149,14 @@ class Recorder:
         stack = self._stack()
         return stack[-1] if stack else None
 
+    def add(self, key: str, amount: float = 1) -> None:
+        """Add ``amount`` to attr ``key`` of this thread's innermost open
+        span, for a count made below the layer that opened it; nothing
+        where no span is open."""
+        top = self.innermost()
+        if top is not None:
+            top.attrs[key] = top.attrs.get(key, 0) + amount
+
     def between(self, t0_ns: int, t1_ns: int) -> List[Span]:
         """Finished spans that start in ``[t0_ns, t1_ns)``, by start."""
         with self._lock:
@@ -214,6 +224,7 @@ _RECORDER = Recorder()
 _RECORDER.listen()
 
 span = _RECORDER.span
+add = _RECORDER.add
 between = _RECORDER.between
 totals = _RECORDER.totals
 unowned = _RECORDER.unowned

@@ -132,7 +132,7 @@ def run_study(
         from repro.analysis.identifiability import analyze_model
 
         structural = []
-        with spans.span("solve.identify", models=len(models)):
+        with spans.span("solve.identify", models=len(models), reused=0):
             for name in sorted(models):
                 m = models[name]
                 structural += [

@@ -282,6 +282,139 @@ def test_run_study_refuses_unidentifiable_rung_unless_forced():
     assert "twin_madd" in profile.fits
 
 
+def _zoo_rows(model, n_rows=14, seed=0):
+    """A battery-like train split for a zoo rung: each row a random mix of
+    matmul, stream and empty-kernel features over six decades."""
+    rng = np.random.default_rng(seed)
+    F = 10.0 ** rng.uniform(0.0, 6.0, (n_rows, len(model.feature_names)))
+    return F * (rng.uniform(size=F.shape) < 0.7)
+
+
+def _identifiability_cases():
+    """(name, model, aligned features): the zoo's three rungs and the
+    fixture models above, each with its own battery."""
+    from repro.studies.zoo import MODEL_ZOO
+
+    cases = []
+    for e in MODEL_ZOO:
+        m = e.model()
+        cases.append((e.name, m, _zoo_rows(m)))
+    fixtures = {
+        "twin": ("p_a * f_x + p_b * f_x",
+                 [{"f_x": 1.0}, {"f_x": 2.0}, {"f_x": 3.0}]),
+        "dead": ("p_a * f_x + p_b * f_y", [{"f_x": 1.0}, {"f_x": 2.0}]),
+        "thin": ("p_a * f_x + p_b * f_y", [{"f_x": 1.0, "f_y": 2.0}]),
+        "wobbly": ("p_a * f_x + p_b * f_y + p_c * f_z",
+                   [{"f_x": 1.0, "f_y": 0.0, "f_z": 1.0 + 1e-6},
+                    {"f_x": 0.0, "f_y": 1.0, "f_z": 1.0 + 1e-6},
+                    {"f_x": 1.0, "f_y": 1.0, "f_z": 2.0 - 1e-6}]),
+    }
+    for name, (expr, rows) in fixtures.items():
+        m = Model("f_t", expr)
+        cases.append((name, m, m.align(rows, missing="zero")))
+    return cases
+
+
+def _eager_param_jacobian(model, points, features):
+    """The design matrix as it was computed before it was compiled: an
+    eager ``jax.jacfwd`` of ``batched_eval`` at each point."""
+    from repro.core.model import _param_dtype
+
+    dt = _param_dtype()
+    F = jnp.asarray(features, dt)
+    return np.stack([np.asarray(jax.jacfwd(
+        lambda p: model.batched_eval(p, F))(jnp.asarray(p, dt)), np.float64)
+        for p in points])
+
+
+def _same_diagnostics(got, want):
+    assert [(d.severity, d.code, d.location) for d in got] \
+        == [(d.severity, d.code, d.location) for d in want]
+    for g, w in zip(got, want):
+        assert g.details.keys() == w.details.keys()
+        for k, v in w.details.items():
+            if isinstance(v, float):
+                assert g.details[k] == pytest.approx(v, rel=1e-6), k
+            else:
+                assert g.details[k] == v, k
+
+
+@pytest.mark.parametrize(
+    "name,model,features",
+    [pytest.param(*case, id=case[0]) for case in _identifiability_cases()])
+def test_compiled_design_matrix_matches_eager_autodiff(
+        name, model, features, monkeypatch):
+    from repro.analysis.identifiability import _probe_points
+
+    points = _probe_points(len(model.param_names))
+    J = model.param_jacobian(points, features)
+    assert J.dtype == np.float64
+    assert J.shape == (len(points), len(features), len(model.param_names))
+    np.testing.assert_allclose(
+        J, _eager_param_jacobian(model, points, features), rtol=1e-6)
+
+    got = analyze_model(model, features, f"model:{name}")
+    monkeypatch.setattr(Model, "param_jacobian", _eager_param_jacobian)
+    _same_diagnostics(got, analyze_model(model, features, f"model:{name}"))
+
+
+def test_equal_models_reuse_the_compiled_design_matrix():
+    """The second analysis of re-created, equal zoo rungs (as every
+    profile of a study makes them) compiles nothing: each rung's
+    Jacobian program comes from the process-wide cache."""
+    from repro import spans
+    from repro.studies.zoo import zoo_models
+
+    def identify(models):
+        with spans.span("solve.identify", models=len(models),
+                        reused=0) as s:
+            diags = [analyze_model(m, _zoo_rows(m, n_rows=17), name)
+                     for name, m in sorted(models.items())]
+        return s.attrs, diags
+
+    first, want = identify(zoo_models())
+    second, got = identify(zoo_models())
+    assert second.get("compiles", 0) == 0
+    assert second["reused"] == second["models"] == 3
+    assert got == want
+
+
+def test_shared_compiled_cache_is_fifo_bounded(monkeypatch):
+    from repro.core import model as model_mod
+
+    monkeypatch.setattr(model_mod, "_SHARED_COMPILED", {})
+    monkeypatch.setattr(model_mod, "_SHARED_COMPILED_MAX", 2)
+    builds = []
+
+    def build(tag):
+        def make():
+            builds.append(tag)
+            return lambda: tag
+        return make
+
+    exprs = [f"p_a * f_x + {c} * p_b * f_y" for c in (1.0, 2.0, 3.0)]
+    kept = []
+    for i, expr in enumerate(exprs):
+        m = Model("f_t", expr)
+        fn, built = m.compiled(("k",), build(i))
+        assert built and fn() == i
+        kept.append((m, fn))
+    assert builds == [0, 1, 2]
+    assert len(model_mod._SHARED_COMPILED) == 2
+    # the oldest went first: an equal model builds it again, while the
+    # newer ones are still shared
+    for i, expr in ((2, exprs[2]), (0, exprs[0])):
+        fn, built = Model("f_t", expr).compiled(("k",), build(i))
+        assert built == (i == 0) and fn() == i
+    assert builds == [0, 1, 2, 0]
+    assert len(model_mod._SHARED_COMPILED) == 2
+    # an instance keeps its own programs, whatever the shared cache evicts
+    m1, fn1 = kept[1]
+    assert (m1.signature(), "k") not in model_mod._SHARED_COMPILED
+    assert m1.compiled(("k",), build(1)) == (fn1, False)
+    assert builds == [0, 1, 2, 0]
+
+
 # ---------------------------------------------------------------------------
 # cache-signature hazards
 # ---------------------------------------------------------------------------

@@ -101,6 +101,20 @@ def test_compile_events_without_an_open_span_are_unowned():
     assert after["compile_s"] > before["compile_s"]
 
 
+def test_add_counts_into_the_innermost_span_only():
+    rec = Recorder()
+    rec.add("reused")                    # no span open: nothing
+    with rec.span("outer", reused=0) as outer:
+        with rec.span("inner") as inner:
+            rec.add("reused")
+            rec.add("reused", 2)
+        rec.add("reused")
+    assert inner.attrs == {"reused": 3} and outer.attrs == {"reused": 1}
+    assert rec.unowned() == dict.fromkeys(
+        ("compiles", "cache_hits", "cache_misses", "compile_s"), 0)
+    assert rec.totals()["outer"]["reused"] == 1
+
+
 def test_between_is_half_open_on_start():
     rec = Recorder()
     with rec.span("x") as x:
