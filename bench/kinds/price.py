@@ -1,9 +1,14 @@
 """An open loop of price requests into ``CoalescingBatcher.submit``.
 
 Set-up calibrates a profile on the chip (``repro.calibrate --zoo``),
-opens a ``PerfSession`` on it, prices every catalog item once, and warms
-the batched evaluator at every row count up to the batcher's
-``max_batch``, so that nothing compiles in the window.  The window sends
+opens a ``PerfSession`` on it, prices every catalog item once, builds
+the families a service that prices the catalog's generators holds
+(:func:`_families`), and warms the batched evaluator at every row count
+up to the batcher's ``max_batch``, so that the evaluator compiles
+nothing in the window.  A never-seen kernel's first sight (a family of
+another generator with its probe traces, an exact trace with its
+arrays, a Pallas subject's jaxpr) is the window's own work, as it is a
+running service's.  The window sends
 each request when it is due (``bench.traffic``), from this one thread;
 the batcher's drainer answers them.  After the window every request gets
 ``wait_after_s`` (a minute) to be answered.
@@ -58,6 +63,7 @@ def run(h) -> Dict[str, Any]:
     flat = [i for v in cat.values() for i in v]
     session.predict_batch([sent[id(i)] for i in flat],
                           names=[i.label for i in flat])
+    _families(session, cat["battery"])
     max_batch = int(tr["max_batch"])
     engine = session.predict_engine
     for n in range(1, max_batch + 1):
@@ -83,6 +89,24 @@ def run(h) -> Dict[str, Any]:
     print(f"[bench] reference took {time.perf_counter() - t_ref:.1f} s",
           flush=True)
     return out
+
+
+def _families(session, catalog) -> None:
+    """The count store of a service that has priced its catalog's
+    generators a while: the symbolic family of every member of each
+    generator the catalog draws on, taken from the generator's own
+    argument space, not from the requests to come."""
+    import json
+
+    from repro.core.uipick import ALL_GENERATORS
+
+    names = {json.loads(i.kernel.family.key)["gen"] for i in catalog
+             if i.kernel.family is not None}
+    families = {k.family.key: k.family for g in ALL_GENERATORS
+                if g.name in names for k in g.variants({})
+                if k.family is not None}
+    for fam in families.values():
+        session.engine.symbolic(fam)
 
 
 def _serve(h, tr, session, batcher, sent, cat, new, rate):

@@ -393,3 +393,34 @@ def test_synthetic_trace_by_hand():
     want = 100 * least / (40e-6 + 25e-6)
     got = core.reader("kernels.battery_roofline")(ctx)
     assert got == pytest.approx(want)
+
+
+def test_pausewatch_names_the_thread_that_holds_the_gil(tmp_path):
+    """A stop of Python in the process, planted by a regular expression
+    that backtracks in C while it holds the GIL: the watcher process sees
+    the main thread use CPU for it, and did not stop itself."""
+    import math
+    import re
+    import time
+
+    from bench import pausewatch
+
+    # each further "a" doubles the backtracking: size the call to ~0.8 s
+    t = time.monotonic()
+    re.match(r"(a+)+$", "a" * 18 + "b")
+    n = 18 + max(0, round(math.log2(0.8 / max(time.monotonic() - t, 1e-6))))
+
+    def hold():
+        time.sleep(0.3)
+        t = time.monotonic()
+        re.match(r"(a+)+$", "a" * n + "b")
+        time.sleep(0.3)
+        return time.monotonic() - t
+
+    held, rep = pausewatch.watched(hold, tmp_path)
+    assert held > 0.4, "the planted call was too short to show"
+    stop = max(rep["stops"], key=lambda s: s["stop_s"])
+    assert stop["stop_s"] > 0.3
+    assert stop["threads"][0][0] == "MainThread"
+    assert stop["process_cpu_s"] > 0.5 * stop["stop_s"]
+    assert stop["watcher_gap_s"] < 0.5 * stop["stop_s"]
